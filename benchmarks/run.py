@@ -78,6 +78,9 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     exp_dir()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_dse_service,
         bench_generalization,

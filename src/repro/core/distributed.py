@@ -78,6 +78,16 @@ def batch_axes(mesh: Mesh) -> Tuple[Tuple[str, ...], Tuple[str, ...]]:
     return search_axes(mesh), pop_axes(mesh)
 
 
+def spec_axes(part) -> Tuple[str, ...]:
+    """One ``PartitionSpec`` entry as a tuple of mesh-axis names.
+    ``PartitionSpec`` stores a one-axis group as the bare name
+    (``P(None, ("data",))[1] == "data"``), so readers of a spec go through
+    here: ``None`` -> ``()``, ``"data"`` -> ``("data",)``."""
+    if part is None:
+        return ()
+    return part if isinstance(part, tuple) else (part,)
+
+
 def batch_spec(mesh: Mesh, ndim: int, pop_dim: Optional[int] = None) -> P:
     """PartitionSpec for a batched array: dim 0 over ``search``, optional
     ``pop_dim`` over ``pod``/``data``, everything else replicated.  Missing
@@ -101,12 +111,9 @@ def shape_spec(
     spec = batch_spec(mesh, len(shape), pop_dim)
     parts = []
     for dim, part in enumerate(spec):
-        if part is None:
-            parts.append(None)
-            continue
-        names = part if isinstance(part, tuple) else (part,)
+        names = spec_axes(part)
         group = int(np.prod([mesh.shape[a] for a in names]))
-        parts.append(part if shape[dim] % group == 0 else None)
+        parts.append(part if names and shape[dim] % group == 0 else None)
     return P(*parts)
 
 

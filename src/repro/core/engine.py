@@ -74,7 +74,7 @@ from repro.core.objectives import (
     make_weighted_objective,
     pareto_scalar,
 )
-from repro.imc.cost import evaluate_designs_arrays
+from repro.imc.cost import VF_RTOL, evaluate_designs_arrays
 from repro.imc.tech import TECH, TechParams
 from repro.workloads.pack import WorkloadSet
 
@@ -310,7 +310,7 @@ def _valid_vt_mask(tech: TechParams) -> np.ndarray:
         (tech.v_nominal - tech.v_th) ** tech.alpha_power / tech.v_nominal
     )
     t_min = k * v / (v - np.float32(tech.v_th)) ** np.float32(tech.alpha_power)
-    return t >= t_min
+    return t >= t_min * np.float32(1.0 - VF_RTOL)
 
 
 # the six jointly-constrained fields of the direct seeder: the demand

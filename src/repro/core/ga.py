@@ -77,8 +77,9 @@ def gen_kernel_enabled() -> bool:
     (``repro.kernels.ga_gen_step``).  Read at TRACE time: set
     ``REPRO_GA_KERNEL=1`` before the first GA launch of the process (a
     cached jit compiled with the flag off will not retrace).  Off by
-    default — the lax fused path is faster on CPU hosts; the kernel
-    targets TPU runs and is parity-pinned in interpret mode."""
+    default, and usable in interpret mode only: the TPU compiler refuses
+    the kernel (tests/test_tpu_compile.py pins the refusal), so a TPU run
+    with the flag set fails at its first launch."""
     return _env_flag("REPRO_GA_KERNEL", False)
 
 
@@ -1021,6 +1022,35 @@ def _pareto_epilogue(genomes_hist, objs_hist, top_k: int) -> ParetoThin:
     conv = jax.lax.cummin(jnp.min(flat_s.reshape(G1, P), axis=1))
     return ParetoThin(top_genomes=top_g, top_vectors=top_v, top_scores=top_s,
                       n_kept=n_kept, convergence=conv)
+
+
+def pareto_mask_bytes(pop_size: int, generations: int) -> int:
+    """Device bytes the front epilogue's dominance masks take: two (N, N)
+    boolean arrays over the N = (G+1)*P history rows (the v5e compile
+    rehearsal measures ~2.0 bytes per N^2 for N >= 1e4;
+    tests/test_tpu_compile.py)."""
+    n = (int(generations) + 1) * int(pop_size)
+    return 2 * n * n
+
+
+# (P, G) ladder for a Pareto request sized to the device, smallest first
+PARETO_BUDGETS = ((256, 20), (512, 20), (1024, 20), (1024, 50), (1024, 100))
+# the masks may take this share of device memory; the rest holds the
+# history, the tables and the program's other temporaries
+PARETO_MASK_SHARE = 0.5
+
+
+def pick_pareto_budget(device_bytes: int) -> Tuple[int, int]:
+    """The largest (P, G) of ``PARETO_BUDGETS`` whose epilogue masks take
+    at most ``PARETO_MASK_SHARE`` of ``device_bytes``; raises if even the
+    smallest does not fit."""
+    fit = [b for b in PARETO_BUDGETS
+           if pareto_mask_bytes(*b) <= PARETO_MASK_SHARE * device_bytes]
+    if not fit:
+        raise ValueError(
+            f"no Pareto budget fits {PARETO_MASK_SHARE:.0%} of "
+            f"{device_bytes} device bytes")
+    return fit[-1]
 
 
 _PARETO_STATICS = _GA_STATICS + ("top_k", "history")

@@ -3,7 +3,7 @@
 # Bump on any change that can move a result bit for identical inputs; the
 # service result cache (serve.cache.request_key) keys on it, so persisted
 # entries from an older model can never be served against a newer one.
-COST_MODEL_VERSION = "2"
+COST_MODEL_VERSION = "3"  # 3: the V/f check admits the nominal-point tie
 
 from repro.imc.tech import TECH, TechParams  # noqa: F401
 from repro.imc.cost import (  # noqa: F401

@@ -69,11 +69,21 @@ def _ceil_div(a, b):
     return jnp.ceil(a / b)
 
 
+# Relative slack of the V/f check.  The nominal point (V_nominal, 1 ns)
+# lies exactly on t_min by construction of k, and float32 rounding of the
+# alpha-power law lands it on either side depending on the device and on
+# how XLA fuses the expression: on a TPU v5e the table and dense paths
+# disagreed, and the dense path missed the tie by more than 1e-6.  Every
+# other point of the grids up to density 4 clears t_min by more than
+# 8e-4, so the slack only settles that tie, as valid, on every backend.
+VF_RTOL = 1e-4
+
+
 def design_valid(d: DesignArrays, tech: TechParams = TECH) -> jnp.ndarray:
     """V/f self-consistency (P,): alpha-power-law minimum cycle at V_op."""
     k = (tech.v_nominal - tech.v_th) ** tech.alpha_power / tech.v_nominal
     t_min = k * d.v_op / (d.v_op - tech.v_th) ** tech.alpha_power
-    return d.t_cycle_ns >= t_min
+    return d.t_cycle_ns >= t_min * (1.0 - VF_RTOL)
 
 
 def area_mm2(d: DesignArrays, tech: TechParams = TECH) -> jnp.ndarray:

@@ -27,7 +27,11 @@ only exactly-representable re-expressions of the lax ops:
     op-for-op.
 
 Tested in interpret mode against the lax generation step
-(tests/test_fused_gen.py); compiled lowering targets TPU hosts.
+(tests/test_fused_gen.py).  The TPU compiler refuses the kernel: Mosaic
+has no lowering for the ``rev`` that ``jnp.flip`` emits in the partner
+exchange, and with that exchange done by lane rolls it next refuses the
+rank-3 reshapes of the one-hot selects.  tests/test_tpu_compile.py pins
+the refusal.
 """
 from __future__ import annotations
 
@@ -40,6 +44,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 
 from repro.core import space
+from repro.imc.cost import VF_RTOL
 from repro.imc.tech import TECH, TechParams
 
 
@@ -260,7 +265,7 @@ def _gen_kernel(
     # design_valid, inlined
     kv = (tech.v_nominal - tech.v_th) ** tech.alpha_power / tech.v_nominal
     t_min = kv * d["v_op"] / (d["v_op"] - tech.v_th) ** tech.alpha_power
-    valid = d["t_cycle_ns"] >= t_min
+    valid = d["t_cycle_ns"] >= t_min * (1.0 - VF_RTOL)
 
     # ---- indexed objective (where-chain == trailing-axis stack + gather)
     e = energy.max(axis=-1)
@@ -278,9 +283,12 @@ def _gen_kernel(
     okey = jnp.where(bits < 0, -(bits & jnp.int32(0x7FFFFFFF)), bits)
     N = _next_pow2(2 * P)
     iota2p = jax.lax.broadcasted_iota(jnp.int32, (1, N), 1)[0]
-    key_pad = jnp.concatenate(
-        [okey, jnp.full((N - 2 * P,), jnp.int32(2**31 - 1))])
-    val_pad = jnp.concatenate([alls, jnp.zeros((N - 2 * P,), jnp.float32)])
+    key_pad, val_pad = okey, alls
+    if N > 2 * P:  # no zero-width pad when 2P is already a power of two
+        key_pad = jnp.concatenate(
+            [okey, jnp.full((N - 2 * P,), jnp.int32(2**31 - 1))])
+        val_pad = jnp.concatenate(
+            [alls, jnp.zeros((N - 2 * P,), jnp.float32)])
     _, sidx, sval = _bitonic_sort(key_pad, iota2p, val_pad, N)
     new_pop_ref[...] = _sel_rows(sidx[:P], allg, 2 * P)
     new_scores_ref[0, :] = sval[:P]

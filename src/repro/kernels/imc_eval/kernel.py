@@ -5,9 +5,11 @@ whole SET of workloads' layer tables — as a VMEM-tiled 3-D grid in ONE
 kernel launch:
 
   * designs live on the LANE axis (tile 128, the VPU vector width),
-  * layers live on the SUBLANE axis (tile 8),
+  * layers live on the SUBLANE axis (tile 8): the layer table is laid out
+    (W, L, 6), so a (SUB, 6) feature block is 8 sublanes by the full
+    6-wide feature axis — the shape Mosaic's (8, 128) block rule accepts,
   * workloads are a middle grid axis (W is small; each (p, w) cell owns
-    one row of the (W, P) accumulators),
+    one (1, 128) row of the (W, 1, P) accumulators),
   * grid = (P // 128, W, L // 8); the layer axis is the innermost
     ("arbitrary") grid dim so each (design-tile, workload)'s partial sums
     accumulate in-place in the output block across layer steps,
@@ -31,7 +33,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.imc.tech import TECH, TechParams
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 LANE = 128  # designs per tile (lane axis)
 SUB = 8  # layers per tile (sublane axis)
@@ -45,12 +46,12 @@ def default_interpret() -> bool:
 
 
 def _eval_kernel(
-    feats_ref,  # (1, 6, SUB)   this workload's layer-features tile
-    mask_ref,  # (1, 1, SUB)
+    feats_ref,  # (1, SUB, 6)   this workload's layer-features tile
+    mask_ref,  # (1, SUB, 1)
     d_ref,  # (9, LANE)  design params tile (param-major)
-    energy_ref,  # (1, LANE)  accumulated outputs, one (w, p) row each
-    latency_ref,  # (1, LANE)
-    demand_ref,  # (1, LANE)
+    energy_ref,  # (1, 1, LANE)  accumulated outputs, one (w, p) row each
+    latency_ref,  # (1, 1, LANE)
+    demand_ref,  # (1, 1, LANE)
     *,
     tech: TechParams,
 ):
@@ -61,15 +62,11 @@ def _eval_kernel(
     g_chip, v_op, bits = d[4:5], d[5:6], d[6:7]
     t_cyc, glb_mb = d[7:8], d[8:9]
 
-    f = feats_ref[0]  # (6, SUB)
-    mk = mask_ref[0].astype(jnp.float32)  # (1, SUB)
+    f = feats_ref[0]  # (SUB, 6)
+    mkc = mask_ref[0]  # (SUB, 1)
 
     # (SUB, 1) feature columns x (1, LANE) design rows -> (SUB, LANE) tiles
-    def col(i):
-        return f[i : i + 1, :].T  # (SUB, 1)
-
-    M, K, N, Ain, Aout, G = (col(i) for i in range(6))
-    mkc = mk.T  # (SUB, 1)
+    M, K, N, Ain, Aout, G = (f[:, i : i + 1] for i in range(6))
 
     phases = jnp.float32(tech.input_bits)
     cpw = jnp.ceil(jnp.float32(tech.weight_bits) / bits)  # (1, LANE)
@@ -100,15 +97,15 @@ def _eval_kernel(
 
     @pl.when(li == 0)
     def _init():
-        energy_ref[...] = energy
-        latency_ref[...] = latency
-        demand_ref[...] = demand
+        energy_ref[0] = energy
+        latency_ref[0] = latency
+        demand_ref[0] = demand
 
     @pl.when(li > 0)
     def _acc():
-        energy_ref[...] += energy
-        latency_ref[...] += latency
-        demand_ref[...] += demand
+        energy_ref[0] += energy
+        latency_ref[0] += latency
+        demand_ref[0] += demand
 
 
 def imc_eval_pallas_multi(
@@ -135,30 +132,29 @@ def imc_eval_pallas_multi(
     if Pp != P:
         ones = jnp.ones((9, Pp - P), jnp.float32)
         dT = dT.at[:, P:].set(ones)
-    fT = jnp.zeros((W, 6, Lp), jnp.float32)
-    fT = fT.at[:, :, :L].set(jnp.transpose(feats, (0, 2, 1)).astype(jnp.float32))
-    mk = jnp.zeros((W, 1, Lp), jnp.float32)
-    mk = mk.at[:, 0, :L].set(mask.astype(jnp.float32))
+    # zero-padded layers are masked out, so their features never count
+    fp = jnp.pad(feats.astype(jnp.float32), ((0, 0), (0, Lp - L), (0, 0)))
+    mk = jnp.pad(mask.astype(jnp.float32), ((0, 0), (0, Lp - L)))[..., None]
 
     grid = (Pp // LANE, W, Lp // SUB)
-    out_shape = [jax.ShapeDtypeStruct((W, Pp), jnp.float32)] * 3
-    out_spec = pl.BlockSpec((1, LANE), lambda p, w, l: (w, p))
+    out_shape = [jax.ShapeDtypeStruct((W, 1, Pp), jnp.float32)] * 3
+    out_spec = pl.BlockSpec((1, 1, LANE), lambda p, w, l: (w, 0, p))
     energy, latency, demand = pl.pallas_call(
         functools.partial(_eval_kernel, tech=tech),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 6, SUB), lambda p, w, l: (w, 0, l)),
-            pl.BlockSpec((1, 1, SUB), lambda p, w, l: (w, 0, l)),
+            pl.BlockSpec((1, SUB, 6), lambda p, w, l: (w, l, 0)),
+            pl.BlockSpec((1, SUB, 1), lambda p, w, l: (w, l, 0)),
             pl.BlockSpec((9, LANE), lambda p, w, l: (0, p)),
         ],
         out_specs=[out_spec, out_spec, out_spec],
         out_shape=out_shape,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(fT, mk, dT)
-    return energy[:, :P], latency[:, :P], demand[:, :P]
+    )(fp, mk, dT)
+    return energy[:, 0, :P], latency[:, 0, :P], demand[:, 0, :P]
 
 
 def imc_eval_pallas(

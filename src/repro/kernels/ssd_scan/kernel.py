@@ -27,8 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._compat import CompilerParams as _CompilerParams
-
 
 def _ssd_kernel(
     x_ref,  # (1, Q, P)  head inputs
@@ -136,7 +134,7 @@ def ssd_scan_pallas(
             jax.ShapeDtypeStruct((BH, N, P), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,

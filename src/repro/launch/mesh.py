@@ -16,7 +16,10 @@ Mesh axes:
   * ``model`` — Megatron-style tensor/expert/sequence parallelism.
 
 ``make_search_mesh`` builds the 2-D ``(search, data)`` layout used by the
-sharded search drivers; every constructor here degrades gracefully when the
+sharded search drivers.  Every mesh here declares its axes ``Auto``
+(GSPMD propagates shardings from the committed inputs), the mode the
+search stack is written for; ``jax.make_mesh`` would otherwise default
+to ``Explicit`` axes.  Every constructor degrades gracefully when the
 host exposes fewer devices than requested (axis sizes clamp to the device
 budget, down to 1 on a single-device host), so tests and benches run
 unchanged from laptops to pods.
@@ -27,7 +30,11 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto(axes: Tuple[str, ...]) -> Tuple[AxisType, ...]:
+    return (AxisType.Auto,) * len(axes)
 
 
 def make_production_mesh(*, multi_pod: bool = False, searches: int = 1) -> Mesh:
@@ -38,12 +45,12 @@ def make_production_mesh(*, multi_pod: bool = False, searches: int = 1) -> Mesh:
     if searches > 1:
         shape = (searches,) + shape
         axes = ("search",) + axes
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
     """Arbitrary mesh over however many devices exist (tests, elasticity)."""
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=_auto(axes))
 
 
 def _fit_axis(requested: int, remaining: int) -> int:
@@ -76,7 +83,7 @@ def make_test_mesh(data: int = 1, model: int = 1, search: int = 1) -> Mesh:
         shape = (sizes["data"], sizes["model"])
         axes = ("data", "model")
     devs = np.asarray(jax.devices()[: int(np.prod(shape))]).reshape(shape)
-    return Mesh(devs, axes)
+    return Mesh(devs, axes, axis_types=_auto(axes))
 
 
 def make_search_mesh(
@@ -102,7 +109,7 @@ def make_search_mesh(
         searches = _fit_axis(searches, n)
         pop = _fit_axis(pop, n // searches)
     devs = np.asarray(jax.devices()[: searches * pop]).reshape(searches, pop)
-    return Mesh(devs, ("search", "data"))
+    return Mesh(devs, ("search", "data"), axis_types=_auto(("search", "data")))
 
 
 def mesh_axis_sizes(mesh: Mesh) -> Dict[str, int]:

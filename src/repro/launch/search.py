@@ -92,6 +92,7 @@ from repro.core.search import (
     seed_population,
     separate_search,
 )
+from repro.launch.compile_cache import enable_compile_cache
 from repro.workloads.cnn import PAPER_WORKLOADS, cnn_workload
 from repro.workloads.lm import lm_workload
 from repro.workloads.pack import WorkloadSet, pack_workloads
@@ -382,6 +383,7 @@ def main(argv=None) -> int:
     )
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     mesh = None
     if args.search_mesh:

@@ -8,7 +8,8 @@ import numpy as np
 import pytest
 
 from repro.core import space
-from repro.imc.cost import DesignArrays, area_mm2, evaluate_designs
+from repro.core.engine import _valid_vt_mask
+from repro.imc.cost import DesignArrays, area_mm2, design_valid, evaluate_designs
 from repro.imc.tech import TECH
 from repro.workloads.cnn import PAPER_WORKLOADS, cnn_workload
 from repro.workloads.lm import lm_workload
@@ -60,6 +61,24 @@ def test_voltage_frequency_coupling():
                             pack_workloads([("x", [(1, 8, 8, 8, 8, 1)])]))
     assert not bool(fast.valid[0])
     assert bool(slow.valid[0])
+
+
+def test_nominal_operating_point_is_valid():
+    """(V_nominal, 1 ns) lies exactly on t_min (tech.py normalizes the
+    alpha-power law there): the dense model, its jitted form and the
+    seeder's host mask all call it valid, whatever float32 rounding does
+    to the tie, and the next voltage step down stays invalid."""
+    nominal = _design(v_op=TECH.v_nominal, t_cycle_ns=1.0)
+    below = _design(v_op=0.875, t_cycle_ns=1.0)
+    for valid in (design_valid, jax.jit(design_valid)):
+        assert bool(valid(nominal)[0])
+        assert not bool(valid(below)[0])
+    v_grid = np.asarray(space.SPACE["v_op"], np.float32)
+    t_grid = np.asarray(space.SPACE["t_cycle_ns"], np.float32)
+    mask = _valid_vt_mask(TECH)
+    vi = int(np.argmin(np.abs(v_grid - TECH.v_nominal)))
+    ti = int(np.argmin(np.abs(t_grid - 1.0)))
+    assert mask[vi, ti] and not mask[vi - 1, ti]
 
 
 def test_bits_per_cell_tradeoff(ws):

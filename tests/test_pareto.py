@@ -424,3 +424,15 @@ def test_pareto_scalar_matches_ela_bits(ws):
     v = jnp.asarray(res.objective_vectors)
     np.testing.assert_array_equal(np.asarray(pareto_scalar(v)),
                                   res.top_scores)
+
+
+def test_pick_pareto_budget_sizes_masks_to_the_device():
+    """The largest ladder rung whose two (N, N) epilogue masks fit half the
+    device: P=1024, G=50 on a 16 GiB chip (G=100 needs 19.9 GiB); a device
+    too small for the smallest rung is refused, not silently overrun."""
+    assert ga.pareto_mask_bytes(256, 20) == 2 * (21 * 256) ** 2
+    assert ga.pick_pareto_budget(16 * 2**30) == (1024, 50)
+    smallest = ga.pareto_mask_bytes(*ga.PARETO_BUDGETS[0])
+    assert ga.pick_pareto_budget(2 * smallest) == ga.PARETO_BUDGETS[0]
+    with pytest.raises(ValueError):
+        ga.pick_pareto_budget(2 * smallest - 1)

@@ -14,7 +14,7 @@ from hypothesis import given, settings
 from hypothesis import strategies as st
 
 from repro.core import space
-from repro.core.distributed import batch_axes, batch_spec, shape_spec
+from repro.core.distributed import batch_axes, batch_spec, shape_spec, spec_axes
 from repro.core.ga import _poly_mutation, _sbx
 from repro.imc.cost import DesignArrays, evaluate_designs
 from repro.launch.mesh import (
@@ -237,15 +237,20 @@ def _check_mesh_layout(mesh):
     assert all(a == "search" for a in s_ax)
     assert all(a in ("pod", "data") for a in p_ax)
     # specs: dim 0 is the search group, pop_dim the pop group, rest None
-    spec = batch_spec(mesh, 3, pop_dim=1)
-    assert len(spec) == 3 and spec[2] is None
-    assert spec[0] in (s_ax or None, None) and spec[1] in (p_ax or None, None)
+    spec = _spec_groups(batch_spec(mesh, 3, pop_dim=1))
+    assert len(spec) == 3 and spec[2] == ()
+    assert spec[0] in (s_ax, ()) and spec[1] in (p_ax, ())
     # shape_spec only ever shards a dim its axis-group size divides
     shape = (7, 11, 9)
-    for dim, part in enumerate(shape_spec(mesh, shape, pop_dim=1)):
-        if part is not None:
-            group = int(np.prod([sizes[a] for a in part]))
-            assert shape[dim] % group == 0
+    for dim, names in enumerate(_spec_groups(shape_spec(mesh, shape, pop_dim=1))):
+        group = int(np.prod([sizes[a] for a in names]))
+        assert shape[dim] % group == 0
+
+
+def _spec_groups(spec):
+    """Every entry of a PartitionSpec as a tuple of axis names (() for a
+    replicated dim) — one normalization for all the layout assertions."""
+    return tuple(spec_axes(part) for part in spec)
 
 
 @given(st.integers(1, 8), st.integers(1, 8), st.integers(1, 4))
@@ -278,10 +283,9 @@ def test_shape_spec_shards_only_divisible_dims(b, p, nd):
     spec = shape_spec(mesh, shape, pop_dim=1 if len(shape) > 1 else None)
     sizes = mesh_axis_sizes(mesh)
     assert len(spec) == len(shape)
-    for dim, part in enumerate(spec):
-        if part is not None:
-            group = int(np.prod([sizes[a] for a in part]))
-            assert shape[dim] % group == 0
+    for dim, names in enumerate(_spec_groups(spec)):
+        group = int(np.prod([sizes[a] for a in names]))
+        assert shape[dim] % group == 0
 
 
 def test_plain_mesh_layout_invariants():

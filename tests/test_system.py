@@ -135,3 +135,28 @@ def test_train_launcher_end_to_end(tmp_path):
     r = subprocess.run(cmd, capture_output=True, text=True, timeout=600, env=env)
     assert r.returncode == 0, r.stderr[-2000:]
     assert "loss" in r.stdout
+
+
+# ------------------------------------------------------------- compile cache
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(monkeypatch, tmp_path, from_env):
+    """The entry points' cache helper: ``JAX_COMPILATION_CACHE_DIR`` wins
+    and nothing is set in code; otherwise the fixed ``<checkout>/.jax_cache``
+    (no pid, temp name or time in it).  The config is restored before any
+    compile, so the suite stays cache-free."""
+    from repro.launch.compile_cache import CHECKOUT_ROOT, enable_compile_cache
+
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        if from_env:
+            monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+            assert enable_compile_cache() == str(tmp_path)
+            assert jax.config.jax_compilation_cache_dir == prev
+        else:
+            monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+            path = enable_compile_cache()
+            assert (CHECKOUT_ROOT / "chip_smoke.py").is_file()
+            assert path == str(CHECKOUT_ROOT / ".jax_cache") == enable_compile_cache()
+            assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
