@@ -11,9 +11,6 @@ records:
   * requests_per_s         — warm END-TO-END requests/s (submit through
                              drain wall time; each request = a full
                              P x (G+1) GA search),
-  * busy_requests_per_s    — the busy-only figure (wall time inside
-                             ``engine.execute``; what ``ServiceStats.
-                             requests_per_s`` reports),
   * wait/latency p50/p99   — per-request queue-wait and submit-to-result
                              latency percentiles of the recorded warm
                              drain (``ServiceStats`` samples),
@@ -106,7 +103,6 @@ def run(quick: bool = False, verbose: bool = True, mesh=None,
         "cold_s": cold,  # includes trace + XLA compile
         "warm_s": warm,  # cached programs: the steady-state number
         "requests_per_s": n / warm,  # end-to-end: submit through drain
-        "busy_requests_per_s": st.requests_per_s(),  # execute() wall only
         "wait_p50_s": st.wait_p(50), "wait_p99_s": st.wait_p(99),
         "latency_p50_s": st.latency_p(50), "latency_p99_s": st.latency_p(99),
         "designs_per_s": n * per_search / warm,
@@ -129,8 +125,6 @@ def run(quick: bool = False, verbose: bool = True, mesh=None,
             "transfer_bytes": int(eng.transfer_bytes),
             "transfer_bytes_per_launch":
                 eng.transfer_bytes / max(1, eng.launches),
-            "dispatch_gap_p50_s": svc_x.stats.dispatch_gap_p(50),
-            "device_idle_s": svc_x.stats.device_idle_s,
         }
     seq_b = out["transfer"]["sequential"]["transfer_bytes_per_launch"]
     pip_b = out["transfer"]["pipelined"]["transfer_bytes_per_launch"]
@@ -138,7 +132,7 @@ def run(quick: bool = False, verbose: bool = True, mesh=None,
     if verbose:
         print(f"[dse-service] {n} mixed requests: cold {cold:.2f}s "
               f"({programs} programs), warm {warm:.2f}s -> "
-              f"{n/warm:.1f} req/s e2e ({st.requests_per_s():.1f} busy), "
+              f"{n/warm:.1f} req/s e2e, "
               f"{n*per_search/warm:.0f} designs/s, latency p50/p99 "
               f"{_fmt(st.latency_p(50))}/{_fmt(st.latency_p(99))}s "
               f"({svc.stats.launches} launches/drain)")

@@ -1,0 +1,6 @@
+"""Host ms per launch in the dse.dispatch span."""
+from program_spans import span_ms_per_launch
+
+
+def read(run):
+    return span_ms_per_launch(run, "dse.dispatch")

@@ -76,6 +76,7 @@ from repro.core.objectives import (
 )
 from repro.imc.cost import VF_RTOL, evaluate_designs_arrays
 from repro.imc.tech import TECH, TechParams
+from repro.utils import spans
 from repro.workloads.pack import WorkloadSet
 
 BACKENDS = ("jnp", "pallas", "table")
@@ -773,6 +774,9 @@ class BatchPlan:
     slots: int
     pad_w: int
     pad_l: int
+    # the recorder's id of the launch that last ran this plan (set by
+    # ``SearchEngine.dispatch``; ``utils.spans``)
+    launch: Optional[int] = None
 
 
 def plan_key(plan: BatchPlan) -> str:
@@ -1038,9 +1042,13 @@ class SearchEngine:
         a resubmitted drain with zero GA launches; fault partials /
         checkpoints stay full-history and bit-identical either way.
 
-    ``transfer_bytes`` / ``launches`` count device->host bytes and plan
-    launches since construction (or ``reset_transfer_stats()``) — the
-    benches record bytes/launch from them.
+    ``transfer_bytes`` / ``syncs`` / ``launches`` count device->host
+    bytes, blocking device->host reads and plan launches since
+    construction (or ``reset_transfer_stats()``) — the benches record
+    bytes/launch from them.  Every ``dispatch`` and ``harvest`` records
+    its spans (``utils.spans``: ``dse.dispatch``, ``dse.harvest`` and
+    their phases) under the launch id it stamps on the plan and on the
+    ``PendingLaunch``.
     """
 
     def __init__(self, *, mesh=None, max_slots: int = 64,
@@ -1064,6 +1072,7 @@ class SearchEngine:
         self.pipelined = bool(pipelined)
         # device->host transfer telemetry, read by the benches/service
         self.transfer_bytes = 0
+        self.syncs = 0
         self.launches = 0
         self.segment_gens = None if segment_gens is None else int(segment_gens)
         self.segment_retries = int(segment_retries)
@@ -1118,15 +1127,29 @@ class SearchEngine:
 
     def reset_transfer_stats(self) -> None:
         self.transfer_bytes = 0
+        self.syncs = 0
         self.launches = 0
 
     def _sync(self, x) -> np.ndarray:
-        """The engine's ONE device->host sync point: every harvest-side
-        ``np.asarray`` goes through here so ``transfer_bytes`` stays an
-        exact count of what crossed the wire."""
+        """The engine's device->host sync point: every blocking read of a
+        launch's keys, outputs and seed counts goes through here (or, for
+        the segmented path's NaN guard, ``_any_nan``) so
+        ``transfer_bytes`` and ``syncs`` stay exact counts of what crossed
+        the wire and of the blocking reads.  Only ``plan_key`` (the
+        checkpoint directory's name) reads keys outside it."""
         a = np.asarray(x)
         self.transfer_bytes += a.nbytes
+        self.syncs += 1
         return a
+
+    def _any_nan(self, x) -> bool:
+        """Whether device array ``x`` holds a NaN: one blocking one-byte
+        read, counted like ``_sync`` (a ``bool`` conversion, so no
+        ``np.asarray`` of the array itself)."""
+        flag = jnp.isnan(x).any()
+        self.transfer_bytes += flag.nbytes
+        self.syncs += 1
+        return bool(flag)
 
     # ----------------------------------------------------------- execution
     def _padded_request_tables(self, req: SearchRequest, pad_w: int):
@@ -1175,7 +1198,23 @@ class SearchEngine:
         programs on the device, so the harvests' host work overlaps the
         remaining device compute.  The segmented path runs its guarded
         segment chain here (it is a synchronous loop by construction) but
-        still defers its final sync/finalize to ``harvest``."""
+        still defers its final sync/finalize to ``harvest``.
+
+        Records a ``dse.dispatch`` span under a fresh launch id, set on
+        ``plan.launch``."""
+        launch = plan.launch = spans.new_launch()
+        r0 = plan.requests[0]
+        s0 = self.syncs
+        with spans.span("dse.dispatch", launch=launch, slots=plan.slots,
+                        reqs=len(plan.requests), P=int(r0.pop_size),
+                        G=int(r0.generations),
+                        W=sum(r.ws.n for r in plan.requests)) as sp:
+            pend = self._dispatch_plan(plan, mesh, on_progress)
+            sp.set(syncs=self.syncs - s0)
+        return pend
+
+    def _dispatch_plan(self, plan: BatchPlan, mesh, on_progress
+                       ) -> PendingLaunch:
         mesh = self.mesh if mesh is None else mesh
         r0 = plan.requests[0]
         if r0.objective == PARETO and r0.obj_weights is None:
@@ -1190,13 +1229,14 @@ class SearchEngine:
             kw = dict(pop_size=r0.pop_size, generations=r0.generations,
                       init_genomes=prep.init, ctx=prep.ctx, fused=self.fused,
                       top_k=max(int(r.pareto_k) for r in plan.requests))
-            if self.pipelined:
-                thin = run_pareto_batched(prep.k_ga, prep.eval_fn, **kw)
-                return PendingLaunch(plan=plan, pareto=(None, None, thin),
-                                     seed_check=prep.seed_check)
-            gh, oh, thin = run_pareto_batched(prep.k_ga, prep.eval_fn,
-                                              history=True, **kw)
-            return PendingLaunch(plan=plan, pareto=(gh, oh, thin),
+            with spans.span("dse.dispatch.ga"):
+                if self.pipelined:
+                    out = (None, None,
+                           run_pareto_batched(prep.k_ga, prep.eval_fn, **kw))
+                else:
+                    out = run_pareto_batched(prep.k_ga, prep.eval_fn,
+                                             history=True, **kw)
+            return PendingLaunch(plan=plan, pareto=out,
                                  seed_check=prep.seed_check)
         k = self.segment_gens
         if k is not None and 0 < k < int(r0.generations):
@@ -1204,45 +1244,55 @@ class SearchEngine:
                                             on_progress=on_progress)
         prep = self._prepare(plan, mesh, defer_seed=self.pipelined)
         self.launches += 1
-        if self.pipelined:
-            thin = run_ga_batched_thin(
-                prep.k_ga, prep.eval_fn,
-                pop_size=r0.pop_size, generations=r0.generations,
-                init_genomes=prep.init, ctx=prep.ctx, fused=self.fused,
-                top_k=max(int(r.top_k) for r in plan.requests),
-            )
-            return PendingLaunch(plan=plan, thin=thin,
-                                 seed_check=prep.seed_check)
-        ga = run_ga_batched(
-            prep.k_ga, prep.eval_fn,
-            pop_size=r0.pop_size, generations=r0.generations,
-            init_genomes=prep.init, ctx=prep.ctx, fused=self.fused,
-        )
+        kw = dict(pop_size=r0.pop_size, generations=r0.generations,
+                  init_genomes=prep.init, ctx=prep.ctx, fused=self.fused)
+        with spans.span("dse.dispatch.ga"):
+            if self.pipelined:
+                thin = run_ga_batched_thin(
+                    prep.k_ga, prep.eval_fn,
+                    top_k=max(int(r.top_k) for r in plan.requests), **kw)
+                return PendingLaunch(plan=plan, thin=thin,
+                                     seed_check=prep.seed_check)
+            ga = run_ga_batched(prep.k_ga, prep.eval_fn, **kw)
         return PendingLaunch(plan=plan, ga=ga, seed_check=prep.seed_check)
 
     def harvest(self, pending: PendingLaunch) -> List[SearchResult]:
         """Sync a dispatched plan's (small) outputs, finalize, and persist
-        completed results into the cache — the host half of ``execute``."""
-        if pending.seed_check is not None:
-            pending.seed_check()
-        if pending.results is not None:
-            results = pending.results
-        elif pending.pareto is not None:
-            gh, oh, thin = pending.pareto
-            thin_np = ParetoThin(*(self._sync(f) for f in thin))
-            history = None
-            if gh is not None:
-                history = (self._sync(gh), self._sync(oh))
-            results = _finalize_batch_pareto(thin_np, pending.plan.requests,
-                                             history=history)
-        elif pending.thin is not None:
-            thin_np = GAThin(*(self._sync(f) for f in pending.thin))
-            results = _finalize_batch_thin(thin_np, pending.plan.requests)
-        else:
-            # one device->host transfer per field, then pure-numpy prep
-            ga_np = GAResult(*(self._sync(f) for f in pending.ga))
-            results = _finalize_batch(ga_np, pending.plan.requests)
-        self._cache_completed(pending.plan, results)
+        completed results into the cache — the host half of ``execute``.
+        Records a ``dse.harvest`` span under the launch's id: the wait for
+        the outputs, the reads (and the deferred seed check), the
+        finalize."""
+        reqs = pending.plan.requests
+        s0, b0 = self.syncs, self.transfer_bytes
+        with spans.span("dse.harvest", launch=pending.plan.launch) as sp:
+            with spans.span("dse.harvest.wait"):
+                jax.block_until_ready(
+                    [x for x in (pending.thin, pending.ga, pending.pareto)
+                     if x is not None])
+            with spans.span("dse.harvest.sync"):
+                if pending.seed_check is not None:
+                    pending.seed_check()
+                if pending.results is not None:
+                    finalize = lambda: pending.results  # noqa: E731
+                elif pending.pareto is not None:
+                    gh, oh, thin = pending.pareto
+                    thin_np = ParetoThin(*(self._sync(f) for f in thin))
+                    history = None
+                    if gh is not None:
+                        history = (self._sync(gh), self._sync(oh))
+                    finalize = partial(_finalize_batch_pareto, thin_np, reqs,
+                                       history=history)
+                elif pending.thin is not None:
+                    thin_np = GAThin(*(self._sync(f) for f in pending.thin))
+                    finalize = partial(_finalize_batch_thin, thin_np, reqs)
+                else:
+                    # one device->host transfer per field, then numpy prep
+                    ga_np = GAResult(*(self._sync(f) for f in pending.ga))
+                    finalize = partial(_finalize_batch, ga_np, reqs)
+            with spans.span("dse.harvest.finalize"):
+                results = finalize()
+                self._cache_completed(pending.plan, results)
+            sp.set(syncs=self.syncs - s0, bytes=self.transfer_bytes - b0)
         return results
 
     def _cache_completed(self, plan: BatchPlan,
@@ -1264,8 +1314,7 @@ class SearchEngine:
         reqs = plan.requests
         r0 = reqs[0]
         backend, tech = r0.backend, r0.tech
-        S, W, L = plan.slots, plan.pad_w, plan.pad_l
-        packed = list(reqs) + [r0] * (S - len(reqs))
+        packed = list(reqs) + [r0] * (plan.slots - len(reqs))
 
         if mesh is None:
             place = lambda x, **_: x  # noqa: E731 — identity placement
@@ -1274,10 +1323,62 @@ class SearchEngine:
 
             place = partial(place_batched, mesh)
 
-        # slot-packed workload tensors, (W, L)-padded with masked slots;
-        # cached on content so warm drains skip the host pack + transfer
+        with spans.span("dse.dispatch.pack") as sp:
+            ctx, feats, mask, tables, hit = self._pack(plan, packed, place)
+            sp.set(hit=hit)
+            # objective tail: pareto's traced area, traced exponent
+            # weights, or traced (kind, area)
+            if r0.objective == PARETO and r0.obj_weights is None:
+                areas = jnp.asarray([r.area_constr for r in packed],
+                                    jnp.float32)
+                ctx = ctx + (place(areas),)
+                eval_fn = _ctx_eval(PARETO, 0.0, tech, backend)
+            elif r0.obj_weights is not None:
+                w = jnp.asarray([r.obj_weights for r in packed], jnp.float32)
+                ctx = ctx + (place(w),)
+                eval_fn = _ctx_eval(None, float(r0.area_constr), tech, backend)
+            else:
+                codes = jnp.asarray(
+                    [OBJECTIVE_INDEX[r.objective] for r in packed], jnp.int32
+                )
+                areas = jnp.asarray([r.area_constr for r in packed],
+                                    jnp.float32)
+                ctx = ctx + (place(codes), place(areas))
+                eval_fn = _ctx_eval(INDEXED, 0.0, tech, backend)
+
+        with spans.span("dse.dispatch.keys"):
+            # host-side stack (prng keys are tiny arrays, each read back
+            # through ``_sync``): ONE device transfer instead of a stack of
+            # S device-resident scalars
+            keys = place(jnp.asarray(np.stack([self._sync(r.prng_key())
+                                               for r in packed])))
+            ks = jax.vmap(lambda k: jax.random.split(k))(keys)  # (S, 2, 2)
+            # re-commit the derived keys: vmap outputs lose the committed
+            # layout, and an uncommitted jit operand lets GSPMD re-layout
+            # the whole program (bit-parity with the meshless run requires
+            # the exact input placements the sharded drivers always used)
+            k_seed, k_ga = place(ks[:, 0]), place(ks[:, 1])
+
+        with spans.span("dse.dispatch.seed"):
+            init, seed_check = self._init_populations(
+                packed, k_seed, feats, mask, place, tables=tables,
+                defer=defer_seed)
+
+        return _LaunchPrep(packed=packed, place=place, k_ga=k_ga,
+                           init=init, ctx=ctx, eval_fn=eval_fn,
+                           seed_check=seed_check)
+
+    def _pack(self, plan: BatchPlan, packed: List[SearchRequest], place):
+        """The plan's workload operands: slot-packed (W, L)-padded feats
+        and mask, and for the ``table`` backend the stacked per-request
+        tables, each cached on content so warm drains skip the host pack
+        and transfer.  Returns ``(ctx, feats, mask, tables, hit)``, ``hit``
+        when every cache consulted held the plan's operands."""
+        r0 = packed[0]
+        S, W, L = plan.slots, plan.pad_w, plan.pad_l
         fps = tuple(r.ws.fingerprint() for r in packed)
         hit = self._packed_workloads.get((fps, W, L))
+        all_hit = hit is not None
         if hit is None:
             feats = np.zeros((S, W, L, 6), np.float32)
             mask = np.zeros((S, W, L), bool)
@@ -1288,65 +1389,26 @@ class SearchEngine:
             hit = (jnp.asarray(feats), jnp.asarray(mask))
             self._packed_workloads[(fps, W, L)] = hit
         feats, mask = place(hit[0]), place(hit[1])
+        if r0.backend != "table":
+            return (feats, mask), feats, mask, None, all_hit
+        # factorized tables, stacked per request — the SAME arrays
+        # run_search would trace, so parity is exact.  Built BEFORE
+        # seeding: the direct table seeder samples straight from the
+        # stacked demand table.
+        from repro.imc.tables import WorkloadTables
 
-        # host-side stack (prng keys are tiny numpy/jnp arrays): ONE
-        # device transfer instead of a stack of S device-resident scalars
-        keys = place(jnp.asarray(np.stack([np.asarray(r.prng_key())
-                                           for r in packed])))
-        ks = jax.vmap(lambda k: jax.random.split(k))(keys)  # (S, 2, 2)
-        # re-commit the derived keys: vmap outputs lose the committed
-        # layout, and an uncommitted jit operand lets GSPMD re-layout the
-        # whole program (bit-parity with the meshless run requires the
-        # exact input placements the sharded drivers always used)
-        k_seed, k_ga = place(ks[:, 0]), place(ks[:, 1])
-
-        # workload ctx: factorized tables (stacked per request — the SAME
-        # arrays run_search would trace, so parity is exact) or raw tensors.
-        # Built BEFORE seeding: the direct table seeder samples straight
-        # from the stacked demand table.
-        tables = None
-        if backend == "table":
-            from repro.imc.tables import WorkloadTables
-
-            gt = space.grid_token()
-            tables = self._stacked_tables.get((fps, W, tech, gt))
-            if tables is None:
-                per_req = [self._padded_request_tables(r, W) for r in packed]
-                tables = WorkloadTables(*(
-                    jnp.asarray(np.stack([t[f] for t in per_req]))
-                    for f in range(len(per_req[0]))
-                ))
-                self._stacked_tables[(fps, W, tech, gt)] = tables
-            tables = jax.tree_util.tree_map(place, tables)
-            ctx: tuple = (tables,)
-        else:
-            ctx = (feats, mask)
-
-        init, seed_check = self._init_populations(
-            packed, k_seed, feats, mask, place, tables=tables,
-            defer=defer_seed)
-
-        # objective tail: pareto's traced area, traced exponent weights,
-        # or traced (kind, area)
-        if r0.objective == PARETO and r0.obj_weights is None:
-            areas = jnp.asarray([r.area_constr for r in packed], jnp.float32)
-            ctx = ctx + (place(areas),)
-            eval_fn = _ctx_eval(PARETO, 0.0, tech, backend)
-        elif r0.obj_weights is not None:
-            w = jnp.asarray([r.obj_weights for r in packed], jnp.float32)
-            ctx = ctx + (place(w),)
-            eval_fn = _ctx_eval(None, float(r0.area_constr), tech, backend)
-        else:
-            codes = jnp.asarray(
-                [OBJECTIVE_INDEX[r.objective] for r in packed], jnp.int32
-            )
-            areas = jnp.asarray([r.area_constr for r in packed], jnp.float32)
-            ctx = ctx + (place(codes), place(areas))
-            eval_fn = _ctx_eval(INDEXED, 0.0, tech, backend)
-
-        return _LaunchPrep(packed=packed, place=place, k_ga=k_ga,
-                           init=init, ctx=ctx, eval_fn=eval_fn,
-                           seed_check=seed_check)
+        key = (fps, W, r0.tech, space.grid_token())
+        tables = self._stacked_tables.get(key)
+        if tables is None:
+            all_hit = False
+            per_req = [self._padded_request_tables(r, W) for r in packed]
+            tables = WorkloadTables(*(
+                jnp.asarray(np.stack([t[f] for t in per_req]))
+                for f in range(len(per_req[0]))
+            ))
+            self._stacked_tables[key] = tables
+        tables = jax.tree_util.tree_map(place, tables)
+        return (tables,), feats, mask, tables, all_hit
 
     # ------------------------------------------------- segmented execution
     def _place_state(self, state: GAState, place) -> GAState:
@@ -1455,7 +1517,7 @@ class SearchEngine:
                     prep.k_ga, prep.eval_fn, prep.init, ctx=prep.ctx
                 )
                 if thin:
-                    if bool(jnp.isnan(state.scores).any()):
+                    if self._any_nan(state.scores):
                         raise NonFiniteScoreError(
                             "NaN scores in the seed evaluation"
                         )
@@ -1484,14 +1546,15 @@ class SearchEngine:
             attempt = 0
             while True:
                 try:
-                    new_state, (hg, hs) = run_ga_batched_segment(
-                        state, prep.eval_fn, ctx=prep.ctx,
-                        generations=k_gens, total_generations=G,
-                        fused=self.fused,
-                    )
+                    with spans.span("dse.dispatch.ga"):
+                        new_state, (hg, hs) = run_ga_batched_segment(
+                            state, prep.eval_fn, ctx=prep.ctx,
+                            generations=k_gens, total_generations=G,
+                            fused=self.fused,
+                        )
                     if thin:
                         # guard on ONE reduced byte; the history stays put
-                        if bool(jnp.isnan(hs).any()):
+                        if self._any_nan(hs):
                             raise NonFiniteScoreError(
                                 f"NaN scores in segment at generation {done}"
                             )
@@ -1639,7 +1702,7 @@ class SearchEngine:
             check()
             return place(pools, pop_dim=1), None
         check()  # the override merge below syncs the pools anyway
-        pools = np.array(pools)  # writable host copy for the overrides
+        pools = self._sync(pools).copy()  # writable, for the overrides
         for i, r in enumerate(packed):
             if r.init_genomes is not None:
                 pools[i] = np.asarray(r.init_genomes)

@@ -93,6 +93,7 @@ from repro.core.search import (
     separate_search,
 )
 from repro.launch.compile_cache import enable_compile_cache
+from repro.utils import spans
 from repro.workloads.cnn import PAPER_WORKLOADS, cnn_workload
 from repro.workloads.lm import lm_workload
 from repro.workloads.pack import WorkloadSet, pack_workloads
@@ -195,6 +196,7 @@ def serve(args, ws: WorkloadSet, mesh) -> int:
     )
     results = {}
     t0 = time.time()
+    t_spans = time.perf_counter()
     if args.serve_async:
         with AsyncDSEService(**svc_kw) as svc:
             futs = [svc.submit(r, on_progress=on_progress) for r in reqs]
@@ -246,12 +248,20 @@ def serve(args, ws: WorkloadSet, mesh) -> int:
     print(f"[serve] faults: {stats.failures} failures, {stats.retries} "
           f"retries, {stats.partials} partials, {stats.abandoned} abandoned")
     eng = svc.service.engine if args.serve_async else svc.engine
-    print(f"[serve] overlap: pipelined={'on' if args.pipelined else 'off'}, "
-          f"dispatch->harvest gap p50 "
-          f"{_fmt(stats.dispatch_gap_p(50), '.4f')}s, device idle "
-          f"{stats.device_idle_s:.3f}s, "
-          f"{getattr(eng, 'transfer_bytes', 0)} bytes harvested over "
+    snap = spans.snapshot(t_spans)
+    phases = spans.phase_ms(snap)
+    print(f"[serve] host ms/launch (pipelined="
+          f"{'on' if args.pipelined else 'off'}): "
+          + (", ".join(f"{k} {v:.2f}" for k, v in sorted(phases.items()))
+             or "no launch")
+          + f"; {getattr(eng, 'transfer_bytes', 0)} bytes over "
+          f"{getattr(eng, 'syncs', 0)} reads in "
           f"{getattr(eng, 'launches', 0)} engine launches")
+    per = spans.counters(snap)
+    if per:
+        print(f"[serve] per launch: {per['syncs']:.1f} reads, "
+              f"{per['bytes']:.0f} bytes harvested; pack hit both caches "
+              f"in {per['pack_hit']:.0%} of launches")
     if cache is not None:
         print(f"[serve] cache: {stats.cache_hits} submit hits / "
               f"{stats.cache_misses} misses this drain "

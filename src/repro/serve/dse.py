@@ -39,11 +39,14 @@ programs, bit-identical to running each request alone
 (tests/test_engine.py).  ``mesh=`` lays every launch over the 2-D
 (search, population) device mesh.
 
-``ServiceStats`` tracks busy time plus per-request queue-wait and
-end-to-end latency samples (the telemetry deadline policies need) and
+``ServiceStats`` tracks per-request queue-wait and end-to-end latency
+samples on the service clock (the telemetry deadline policies need) and
 deadline misses; ``tests/sim_scheduler.py`` drives all of the above
 against a virtual clock and a stub engine, so every scheduling claim is
-asserted without an XLA launch.
+asserted without an XLA launch.  Where the host time goes is recorded by
+``utils.spans``: the engine's ``dse.dispatch`` / ``dse.harvest`` spans,
+the service's ``dse.resolve`` span per launch, and one record per served
+request (submit, dispatch and resolution stamps on ``perf_counter``).
 """
 from __future__ import annotations
 
@@ -77,6 +80,7 @@ from repro.core.engine import (
     plan_batch,
 )
 from repro.core.objectives import OBJECTIVES
+from repro.utils import spans
 from repro.workloads.pack import WorkloadSet
 
 
@@ -99,9 +103,7 @@ LAUNCH_LOG_WINDOW = 4096
 class ServiceStats:
     """Running drain telemetry (the bench's requests/s row reads these).
 
-    ``busy_s`` is wall time inside ``engine.execute`` only —
-    ``requests_per_s`` is therefore a BUSY throughput, not an end-to-end
-    one.  ``wait_samples`` (dispatch - submit) and ``latency_samples``
+    ``wait_samples`` (dispatch - submit) and ``latency_samples``
     (complete - submit) are per-request, on the service clock, bounded
     to the most recent ``SAMPLE_WINDOW`` completions, so
     ``wait_p``/``latency_p`` percentiles describe what clients recently
@@ -126,14 +128,8 @@ class ServiceStats:
     ``CacheStats.hit_rate()``, which additionally distinguishes the
     memory and disk tiers.
 
-    Launch-overlap telemetry (the pipelined drain's effectiveness):
-    ``dispatch_gap_samples`` records, per launch, how long the dispatched
-    device work waited before its harvest started (harvest start -
-    dispatch end; always 0 on the sequential path, where execute syncs
-    inline), and ``device_idle_s`` accumulates an ESTIMATE of wall time
-    with nothing in flight between one harvest finishing and the next
-    dispatch starting — the overlap win shows up as near-zero idle while
-    the gap stays small.
+    Where a launch's host time goes (dispatch, harvest and resolution
+    phases) is recorded by ``utils.spans``, not here.
 
     Percentiles over empty sample windows are ``None`` (a fresh service
     has no telemetry) — never NaN, which is invalid JSON and poisons
@@ -142,7 +138,6 @@ class ServiceStats:
     submitted: int = 0
     completed: int = 0
     launches: int = 0
-    busy_s: float = 0.0  # wall time spent inside execute()
     deadline_misses: int = 0
     failures: int = 0
     retries: int = 0
@@ -154,12 +149,6 @@ class ServiceStats:
         default_factory=lambda: deque(maxlen=SAMPLE_WINDOW))
     latency_samples: Deque[float] = dataclasses.field(
         default_factory=lambda: deque(maxlen=SAMPLE_WINDOW))
-    dispatch_gap_samples: Deque[float] = dataclasses.field(
-        default_factory=lambda: deque(maxlen=SAMPLE_WINDOW))
-    device_idle_s: float = 0.0
-
-    def requests_per_s(self) -> float:
-        return self.completed / self.busy_s if self.busy_s > 0 else 0.0
 
     def wait_p(self, q: float) -> Optional[float]:
         """Queue-wait percentile in seconds (q in [0, 100]); ``None``
@@ -177,14 +166,8 @@ class ServiceStats:
         total = self.cache_hits + self.cache_misses
         return self.cache_hits / total if total else 0.0
 
-    def dispatch_gap_p(self, q: float) -> Optional[float]:
-        """Dispatch-end -> harvest-start gap percentile in seconds;
-        ``None`` before any launch was harvested."""
-        return _percentile(self.dispatch_gap_samples, q)
-
     def summary(self) -> Dict[str, Optional[float]]:
         return {
-            "requests_per_s": self.requests_per_s(),
             "wait_p50_s": self.wait_p(50), "wait_p99_s": self.wait_p(99),
             "latency_p50_s": self.latency_p(50),
             "latency_p99_s": self.latency_p(99),
@@ -196,8 +179,6 @@ class ServiceStats:
             "cache_hits": self.cache_hits,
             "cache_misses": self.cache_misses,
             "cache_hit_rate": self.cache_hit_rate(),
-            "dispatch_gap_p50_s": self.dispatch_gap_p(50),
-            "device_idle_s": self.device_idle_s,
         }
 
 
@@ -242,9 +223,11 @@ class DSEService:
 
     ``policy`` is a name (fifo / priority / edf) or a
     ``SchedulingPolicy`` instance; ``clock`` (default ``time.monotonic``)
-    is the ONLY time source — submit stamps, waits, deadlines and busy
-    time all read it, so a virtual clock makes every scheduling decision
-    and every stat deterministic (tests/sim_scheduler.py).
+    is the ONLY time source of scheduling — submit stamps, waits and
+    deadlines all read it, so a virtual clock makes every scheduling
+    decision and every stat deterministic (tests/sim_scheduler.py).  The
+    spans and request records (``utils.spans``) stamp ``perf_counter``
+    beside it and decide nothing.
 
     Fault tolerance (both OFF by default — behaviour is then exactly the
     pre-retry service: sync ``step()`` rolls back and re-raises, the
@@ -313,10 +296,6 @@ class DSEService:
         # dispatch/harvest split — they drain sequentially regardless
         self._can_pipeline = (hasattr(self.engine, "dispatch")
                               and hasattr(self.engine, "harvest"))
-        # overlap telemetry: launches currently dispatched-not-harvested,
-        # and when the device last went quiet (None = never launched)
-        self._inflight = 0
-        self._last_harvest_end: Optional[float] = None
         self.result_cache = (
             result_cache if result_cache is not None
             else getattr(self.engine, "result_cache", None)
@@ -349,6 +328,8 @@ class DSEService:
         # SearchRequest.deadline_s at ingest) — what the policy keys on
         self._submit_s: Dict[int, float] = {}
         self._deadline_s: Dict[int, Optional[float]] = {}
+        # per-rid submit stamp on perf_counter, for the request records
+        self._submit_pc: Dict[int, float] = {}
         # signature -> slot size of the last plan that used it: re-plans
         # (mid-drain submits) round small residues UP to this warm program
         # size instead of compiling an exact-size one
@@ -400,6 +381,7 @@ class DSEService:
         self._next_rid += 1
         self.queue.append((rid, req))
         self._submit_s[rid] = now
+        self._submit_pc[rid] = time.perf_counter()
         self._deadline_s[rid] = (
             None if req.deadline_s is None else now + float(req.deadline_s)
         )
@@ -452,7 +434,8 @@ class DSEService:
         (including anything submitted while the launch runs) is free to
         re-plan.  Returns (plan, rids, dispatch stamp); pure queue
         surgery, no device work, so the async front end holds its lock
-        only across this and ``_complete``.
+        only across this and ``_complete``.  The dispatch stamp is on
+        ``perf_counter`` (the request records'; waits use the clock).
 
         Due retries dispatch FIRST, one per step, each re-planned alone
         (quarantine isolation: a poisoned request can only fail its own
@@ -465,7 +448,7 @@ class DSEService:
             plan = plan_batch([e.req], max_slots=self.engine.max_slots,
                               slot_hints=self._slot_hints)[0]
             self.stats.wait_samples.append(now - self._submit_s[e.rid])
-            return plan, [e.rid], now
+            return plan, [e.rid], time.perf_counter()
         if not self.queue:
             return None
         if (self._plans_cache is not None and self._aging_s is not None
@@ -485,7 +468,7 @@ class DSEService:
         now = self.clock()
         for rid in rids:
             self.stats.wait_samples.append(now - self._submit_s[rid])
-        return plan, rids, now
+        return plan, rids, time.perf_counter()
 
     def _drop_wait_samples(self, n: int) -> None:
         for _ in range(min(n, len(self.stats.wait_samples))):
@@ -509,6 +492,7 @@ class DSEService:
         self._drop_wait_samples(len(rids))
         for rid in rids:
             self._submit_s.pop(rid, None)
+            self._submit_pc.pop(rid, None)
             self._deadline_s.pop(rid, None)
             self._attempts.pop(rid, None)
             self._partials.pop(rid, None)
@@ -536,6 +520,7 @@ class DSEService:
         self.stats.partials += 1
         self.stats.completed += 1
         waited = now - self._submit_s.pop(rid)
+        self._submit_pc.pop(rid, None)
         self.stats.wait_samples.append(waited)
         self.stats.latency_samples.append(waited)
         dl = self._deadline_s.pop(rid, None)
@@ -603,6 +588,7 @@ class DSEService:
                 failed.append(rid)
         for rid in failed:  # wait samples already dropped above
             self._submit_s.pop(rid, None)
+            self._submit_pc.pop(rid, None)
             self._deadline_s.pop(rid, None)
             self._attempts.pop(rid, None)
             self._partials.pop(rid, None)
@@ -611,14 +597,18 @@ class DSEService:
         return resolutions, failed
 
     def _complete(
-        self, rids: List[int], results: Sequence[SearchResult], busy_s: float,
+        self, rids: List[int], results: Sequence[SearchResult],
         reqs: Optional[Sequence[SearchRequest]] = None,
+        launch: Optional[int] = None, dispatched: Optional[float] = None,
     ) -> List[Tuple[int, SearchResult]]:
         """Record one finished launch: results, latency/deadline stats,
         result-cache population (``reqs`` aligns with ``rids``; full
-        results only — ``ResultCache.put`` refuses partials itself)."""
+        results only — ``ResultCache.put`` refuses partials itself), and
+        one request record per rid under ``launch`` (the id the engine
+        stamped on the plan; None from an engine that records no spans),
+        with its ``dispatched`` stamp (``perf_counter``)."""
         now = self.clock()
-        self.stats.busy_s += busy_s
+        resolved = time.perf_counter()
         self.stats.launches += 1
         self.launch_log.append(list(rids))
         if len(self.launch_log) > LAUNCH_LOG_WINDOW:
@@ -629,6 +619,8 @@ class DSEService:
             if self.result_cache is not None and reqs is not None:
                 self.result_cache.put(reqs[i], res)
             self.stats.latency_samples.append(now - self._submit_s[rid])
+            spans.request(rid, launch, self._submit_pc.pop(rid, None),
+                          dispatched, resolved)
             dl = self._deadline_s.pop(rid, None)
             self._submit_s.pop(rid, None)
             self._attempts.pop(rid, None)
@@ -667,9 +659,7 @@ class DSEService:
         d = self._dispatch()
         if d is None:
             return swept
-        plan, rids, t0 = d
-        if self._last_harvest_end is not None:
-            self.stats.device_idle_s += max(0.0, t0 - self._last_harvest_end)
+        plan, rids, td = d
         try:
             results = self.engine.execute(plan, **self._progress_kw(rids))
         except Exception as e:
@@ -683,11 +673,9 @@ class DSEService:
             # the kill half of the kill/resume contract
             self._rollback(plan, rids)
             raise
-        te = self.clock()
-        # sequential execute harvests inline: the gap is 0 by definition
-        self.stats.dispatch_gap_samples.append(0.0)
-        self._last_harvest_end = te
-        return swept + self._complete(rids, results, te - t0, plan.requests)
+        with spans.span("dse.resolve", launch=plan.launch, reqs=len(rids)):
+            return swept + self._complete(rids, results, plan.requests,
+                                          plan.launch, td)
 
     def _wait_for_retries(self) -> None:
         """Nothing dispatchable but retries are backed off: sleep the
@@ -699,38 +687,26 @@ class DSEService:
                 self._sleep(dt)
 
     def _harvest_one(
-        self, entry: Tuple[BatchPlan, List[int], float, object, float]
+        self, entry: Tuple[BatchPlan, List[int], float, object]
     ) -> List[Tuple[int, SearchResult]]:
-        """Harvest one in-flight launch ``(plan, rids, t0, pending, td)``:
-        blocks on the device sync, records the dispatch->harvest gap, and
-        completes (or fails, mirroring ``step()``'s fault handling) the
-        launch's requests.  ``busy_s`` gets the HOST time only (dispatch +
-        harvest walls) — the overlapped in-flight window is exactly what
-        the pipelined drain does not spend blocked."""
-        plan, rids, t0, pend, td = entry
-        th = self.clock()
+        """Harvest one in-flight launch ``(plan, rids, td, pending)``:
+        blocks on the device sync and completes (or fails, mirroring
+        ``step()``'s fault handling) the launch's requests."""
+        plan, rids, td, pend = entry
         try:
             results = self.engine.harvest(pend)
         except Exception as e:
-            self._inflight -= 1
-            if self._inflight == 0:
-                self._last_harvest_end = self.clock()
             if self.retry is None:
                 self._rollback(plan, rids)
                 raise
             resolutions, _ = self._handle_failure(plan, rids, e)
             return resolutions
         except BaseException:
-            self._inflight -= 1
             self._rollback(plan, rids)
             raise
-        te = self.clock()
-        self.stats.dispatch_gap_samples.append(max(0.0, th - td))
-        self._inflight -= 1
-        if self._inflight == 0:
-            self._last_harvest_end = te
-        return self._complete(rids, results, (td - t0) + (te - th),
-                              plan.requests)
+        with spans.span("dse.resolve", launch=plan.launch, reqs=len(rids)):
+            return self._complete(rids, results, plan.requests, plan.launch,
+                                  td)
 
     def _stream_pipelined(self) -> Iterator[Tuple[int, SearchResult]]:
         """Double-buffered drain: dispatch plan i+1, THEN harvest plan i,
@@ -738,7 +714,7 @@ class DSEService:
         of the next.  At most one launch is in flight beyond the one
         being harvested; any exception rolls the in-flight launch's
         requests back into the queue before propagating."""
-        prev = None  # (plan, rids, t0, pending, td) still in flight
+        prev = None  # (plan, rids, td, pending) still in flight
         try:
             while True:
                 swept = (self._sweep_deadlines()
@@ -754,10 +730,7 @@ class DSEService:
                         return
                     self._wait_for_retries()
                     continue
-                plan, rids, t0 = d
-                if self._inflight == 0 and self._last_harvest_end is not None:
-                    self.stats.device_idle_s += max(
-                        0.0, t0 - self._last_harvest_end)
+                plan, rids, td = d
                 try:
                     pend = self.engine.dispatch(
                         plan, **self._progress_kw(rids))
@@ -773,9 +746,7 @@ class DSEService:
                 except BaseException:
                     self._rollback(plan, rids)
                     raise
-                td = self.clock()
-                self._inflight += 1
-                cur = (plan, rids, t0, pend, td)
+                cur = (plan, rids, td, pend)
                 if prev is not None:
                     # swap BEFORE harvesting: if the harvest raises, the
                     # outer handler rolls back cur (prev already rolled
@@ -786,7 +757,6 @@ class DSEService:
                     prev = cur
         except BaseException:
             if prev is not None:
-                self._inflight -= 1
                 self._rollback(prev[0], prev[1])
             raise
 
@@ -956,42 +926,56 @@ class AsyncDSEService:
                     # so external clock advances are picked up promptly
                     time.sleep(min(retry_wait, 0.05) or 0.001)
                 continue
-            plan, rids, t0 = d
+            plan, rids, td = d
             # the launch runs WITHOUT the lock: submits land concurrently
             # and join the next dispatch's re-plan (progress callbacks
             # fire here too — lock-free, so they may submit)
             try:
                 results = svc.engine.execute(plan, **svc._progress_kw(rids))
             except BaseException as e:  # noqa: BLE001 — fail the futures, keep serving
-                with self._lock:
-                    if svc.retry is None:
-                        self.service._abandon(rids)
-                        resolved = []
-                        failed = [self._futures.pop(rid, None) for rid in rids]
-                    else:
-                        res2, bad = svc._handle_failure(plan, rids, e)
-                        resolved = [
-                            (self._futures.pop(rid, None), res)
-                            for rid, res in res2
-                        ]
-                        failed = [self._futures.pop(rid, None) for rid in bad]
-                # exceptions set OUTSIDE the lock: done-callbacks fire on
-                # failure too, and they may submit (which takes the lock)
-                for f, res in resolved:
-                    if f is not None:
-                        f.set_result(res)
-                for f in failed:
-                    if f is not None:
-                        f.set_exception(e)
+                self._fail(plan, rids, e)
                 continue
+            self._resolve(plan, rids, td, results)
+
+    def _resolve(self, plan: BatchPlan, rids: List[int], td: float,
+                 results: Sequence[SearchResult]) -> None:
+        """Complete a harvested launch under the lock, then resolve its
+        futures OUTSIDE it (done-callbacks may submit); one ``dse.resolve``
+        span covers both."""
+        svc = self.service
+        with spans.span("dse.resolve", launch=plan.launch, reqs=len(rids)):
             with self._lock:
-                done = svc._complete(rids, results, svc.clock() - t0,
-                                     plan.requests)
-                futs = [(self._futures.pop(rid, None), res) for rid, res in done]
-            # resolve OUTSIDE the lock: done-callbacks may submit
-            for f, res in futs:
+                done = svc._complete(rids, results, plan.requests,
+                                     plan.launch, td)
+                futs = [(self._futures.pop(rid, None), r) for rid, r in done]
+            for f, r in futs:
                 if f is not None:
-                    f.set_result(res)
+                    f.set_result(r)
+
+    def _fail(self, plan: BatchPlan, rids: List[int],
+              e: BaseException) -> None:
+        """A failed launch (dispatch or harvest): abandon its requests, or
+        hand them to the retry policy, under the lock; then resolve or
+        fail their futures OUTSIDE it — done-callbacks fire on failure
+        too, and may submit.  One ``dse.resolve`` span covers both."""
+        svc = self.service
+        with spans.span("dse.resolve", launch=plan.launch, reqs=len(rids)):
+            with self._lock:
+                if svc.retry is None:
+                    svc._abandon(rids)
+                    resolved = []
+                    failed = [self._futures.pop(rid, None) for rid in rids]
+                else:
+                    res2, bad = svc._handle_failure(plan, rids, e)
+                    resolved = [(self._futures.pop(rid, None), r)
+                                for rid, r in res2]
+                    failed = [self._futures.pop(rid, None) for rid in bad]
+            for f, r in resolved:
+                if f is not None:
+                    f.set_result(r)
+            for f in failed:
+                if f is not None:
+                    f.set_exception(e)
 
     def _loop_pipelined(self):
         """The double-buffered worker: dispatch plan i+1 (lock-free — the
@@ -1001,53 +985,16 @@ class AsyncDSEService:
         ``close()`` both finish the in-flight launch before stopping."""
         svc = self.service
 
-        def fail_rids(plan, rids, e):
-            """Failure bookkeeping shared by dispatch and harvest faults
-            (the async twin of step()'s except-arm): returns the futures
-            to resolve/fail, computed under the lock."""
-            if svc.retry is None:
-                svc._abandon(rids)
-                resolved = []
-                failed = [self._futures.pop(rid, None) for rid in rids]
-            else:
-                res2, bad = svc._handle_failure(plan, rids, e)
-                resolved = [(self._futures.pop(rid, None), r)
-                            for rid, r in res2]
-                failed = [self._futures.pop(rid, None) for rid in bad]
-            return resolved, failed
-
         def harvest_entry(entry):
-            plan, rids, t0, pend, td = entry
-            th = svc.clock()
+            plan, rids, td, pend = entry
             try:
                 results = svc.engine.harvest(pend)
             except BaseException as e:  # noqa: BLE001 — fail the futures, keep serving
-                with self._lock:
-                    svc._inflight -= 1
-                    if svc._inflight == 0:
-                        svc._last_harvest_end = svc.clock()
-                    resolved, failed = fail_rids(plan, rids, e)
-                for f, r in resolved:
-                    if f is not None:
-                        f.set_result(r)
-                for f in failed:
-                    if f is not None:
-                        f.set_exception(e)
+                self._fail(plan, rids, e)
                 return
-            te = svc.clock()
-            with self._lock:
-                svc.stats.dispatch_gap_samples.append(max(0.0, th - td))
-                svc._inflight -= 1
-                if svc._inflight == 0:
-                    svc._last_harvest_end = te
-                done = svc._complete(rids, results, (td - t0) + (te - th),
-                                     plan.requests)
-                futs = [(self._futures.pop(rid, None), r) for rid, r in done]
-            for f, r in futs:
-                if f is not None:
-                    f.set_result(r)
+            self._resolve(plan, rids, td, results)
 
-        prev = None  # (plan, rids, t0, pending, td) still in flight
+        prev = None  # (plan, rids, td, pending) still in flight
         while True:
             if prev is None:
                 self._wake.wait()
@@ -1077,12 +1024,6 @@ class AsyncDSEService:
                             self._idle.set()
                     elif nb is not None:
                         retry_wait = max(nb - svc.clock(), 0.0)
-                else:
-                    plan, rids, t0 = d
-                    if (svc._inflight == 0
-                            and svc._last_harvest_end is not None):
-                        svc.stats.device_idle_s += max(
-                            0.0, t0 - svc._last_harvest_end)
             for f, res in partial_futs:
                 if f is not None:
                     f.set_result(res)
@@ -1095,22 +1036,13 @@ class AsyncDSEService:
                 continue
             # dispatch WITHOUT the lock: it only enqueues device work
             # (progress callbacks fire here too, and may submit)
+            plan, rids, td = d
             try:
                 pend = svc.engine.dispatch(plan, **svc._progress_kw(rids))
             except BaseException as e:  # noqa: BLE001 — fail the futures, keep serving
-                with self._lock:
-                    resolved, failed = fail_rids(plan, rids, e)
-                for f, r in resolved:
-                    if f is not None:
-                        f.set_result(r)
-                for f in failed:
-                    if f is not None:
-                        f.set_exception(e)
+                self._fail(plan, rids, e)
                 continue
-            td = svc.clock()
-            with self._lock:
-                svc._inflight += 1
-            cur = (plan, rids, t0, pend, td)
+            cur = (plan, rids, td, pend)
             if prev is not None:
                 to_harvest, prev = prev, cur
                 harvest_entry(to_harvest)

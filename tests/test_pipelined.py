@@ -20,6 +20,9 @@ The contract this module pins (ISSUE 9, perf_opt PR):
     double-buffers dispatch/harvest with unchanged results, yield order
     and launch count.
 """
+import json
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -37,6 +40,7 @@ from repro.core.engine import (
 from repro.core.ga import ga_epilogue_batched
 from repro.core.search import batched_search, run_search
 from repro.serve.dse import AsyncDSEService, DSEService, paper_request_mix
+from repro.utils import spans
 from repro.workloads.cnn import PAPER_WORKLOADS, cnn_workload
 from repro.workloads.pack import pack_workloads
 
@@ -411,23 +415,31 @@ def test_service_pipelined_drain_parity(ws):
 
     def drain(pipelined):
         svc = DSEService(max_slots=8, pipelined=pipelined)
+        t0 = time.perf_counter()
         rids = svc.submit_all(reqs)
         order = [rid for rid, _ in svc.stream()]
-        return svc, rids, order
+        return svc, rids, order, spans.snapshot(t0)
 
-    s_seq, rids_seq, order_seq = drain(False)
-    s_pip, rids_pip, order_pip = drain(True)
+    s_seq, rids_seq, order_seq, snap_seq = drain(False)
+    s_pip, rids_pip, order_pip, snap_pip = drain(True)
     assert order_seq == order_pip  # same plans, same yield boundaries
     assert s_seq.stats.launches == s_pip.stats.launches
     assert s_pip.stats.completed == len(reqs)
     for ra, rb in zip(rids_seq, rids_pip):
         _same_thin(s_pip.results[rb], s_seq.results[ra])
-    # telemetry shape: gap samples per launch, idle accumulates, and the
+    # telemetry shape: each launch of either drain left one dispatch, one
+    # harvest and one resolve span, and every request one record; the
     # summary keys serialize (None or float, never NaN)
-    assert len(s_pip.stats.dispatch_gap_samples) == s_pip.stats.launches
+    for svc, snap in ((s_seq, snap_seq), (s_pip, snap_pip)):
+        assert len(snap.launches) == svc.stats.launches
+        for name in ("dse.dispatch", "dse.harvest", "dse.resolve"):
+            assert sorted(s.launch for s in snap.spans
+                          if s.name == name) == sorted(snap.launches)
+        assert len(snap.requests) == len(reqs)
     summ = s_pip.stats.summary()
-    assert "dispatch_gap_p50_s" in summ and "device_idle_s" in summ
-    assert s_seq.stats.dispatch_gap_p(50) == 0.0  # inline harvests
+    assert "NaN" not in json.dumps(summ)
+    assert not {"requests_per_s", "dispatch_gap_p50_s",
+                "device_idle_s"} & set(summ)
 
 
 def test_async_service_pipelined_parity(ws):

@@ -2,6 +2,8 @@
 accounting — asserted on the virtual-clock harness (tests/sim_scheduler.py),
 no XLA launches.  The real-engine twins (bit-parity, compiled-program
 counts, the async priority-0 jump) live in tests/test_engine.py."""
+import time
+
 import numpy as np
 import pytest
 
@@ -17,6 +19,7 @@ from sim_scheduler import (
 
 from repro.core.engine import PriorityPolicy, get_policy
 from repro.serve.dse import DSEService
+from repro.utils import spans
 
 
 # ---------------------------------------------------------------- policies
@@ -355,8 +358,13 @@ def test_get_policy_rejects_unknown():
 
 def test_empty_step_and_stats_defaults():
     svc, clock, stub = sim_service()
+    t0 = time.perf_counter()
     assert svc.step() == []
-    assert svc.stats.requests_per_s() == 0.0
+    # an empty step resolves no launch and leaves no request record
+    kept, reqs = spans.records()
+    assert not [s for s in kept if s.start >= t0]
+    assert not [r for r in reqs if r.resolve >= t0]
+    assert svc.stats.launches == 0
     # empty sample windows report None, not NaN: a fresh service's
     # summary() must serialize to valid JSON (bench rows read it)
     assert svc.stats.wait_p(50) is None
