@@ -728,6 +728,26 @@ class SearchRequest:
     def prng_key(self) -> jax.Array:
         return self.key if self.key is not None else jax.random.PRNGKey(self.seed)
 
+    def key_data(self) -> np.ndarray:
+        """``np.asarray(prng_key())``, with no device work for a seed.
+
+        An explicit ``key`` is read back.  Under the default
+        ``threefry2x32`` implementation with x64 off, JAX 0.9 keys an
+        integer seed in int64 range as ``uint32[0, seed mod 2**32]``,
+        built here on the host (tests/test_engine.py pins it over edge
+        seeds).  Any other implementation, x64 setting or seed falls back
+        to ``PRNGKey`` itself, so the bytes never rest on the formula
+        where it was not checked."""
+        if self.key is not None:
+            return np.asarray(self.key)
+        seed = self.seed
+        if (isinstance(seed, (int, np.integer))
+                and -2**63 <= int(seed) < 2**63
+                and jax.config.jax_default_prng_impl == "threefry2x32"
+                and not jax.config.jax_enable_x64):
+            return np.array([0, int(seed) % 2**32], np.uint32)
+        return np.asarray(jax.random.PRNGKey(seed))
+
     def signature(self) -> tuple:
         """Traced-shape signature: requests with equal signatures run in
         ONE compiled program.  The ``table`` backend reduced the layer
@@ -797,7 +817,7 @@ def plan_key(plan: BatchPlan) -> str:
             int(r.pop_size), int(r.generations), int(r.top_k),
             int(r.pareto_k), r.tech,
         )).encode())
-        h.update(np.asarray(r.prng_key()).tobytes())
+        h.update(r.key_data().tobytes())
     h.update(repr((int(plan.slots), int(plan.pad_w), int(plan.pad_l))).encode())
     # the grid is a trace-time constant of every program in the plan: a
     # densified space follows a different trajectory from the same requests
@@ -1132,11 +1152,12 @@ class SearchEngine:
 
     def _sync(self, x) -> np.ndarray:
         """The engine's device->host sync point: every blocking read of a
-        launch's keys, outputs and seed counts goes through here (or, for
-        the segmented path's NaN guard, ``_any_nan``) so
+        launch's explicit keys, outputs and seed counts goes through here
+        (or, for the segmented path's NaN guard, ``_any_nan``) so
         ``transfer_bytes`` and ``syncs`` stay exact counts of what crossed
-        the wire and of the blocking reads.  Only ``plan_key`` (the
-        checkpoint directory's name) reads keys outside it."""
+        the wire and of the blocking reads.  A seed's key is built on the
+        host and read nowhere; only ``plan_key`` (the checkpoint
+        directory's name) reads explicit keys outside it."""
         a = np.asarray(x)
         self.transfer_bytes += a.nbytes
         self.syncs += 1
@@ -1346,12 +1367,15 @@ class SearchEngine:
                 ctx = ctx + (place(codes), place(areas))
                 eval_fn = _ctx_eval(INDEXED, 0.0, tech, backend)
 
-        with spans.span("dse.dispatch.keys"):
-            # host-side stack (prng keys are tiny arrays, each read back
-            # through ``_sync``): ONE device transfer instead of a stack of
-            # S device-resident scalars
-            keys = place(jnp.asarray(np.stack([self._sync(r.prng_key())
-                                               for r in packed])))
+        with spans.span("dse.dispatch.keys") as sp:
+            # host-side stack, ONE device transfer for the plan: a seed's
+            # key is built on the host (no device work, no read, so the
+            # dispatch never waits on the device); an explicit key is read
+            # back through ``_sync``
+            keys = place(jnp.asarray(np.stack([
+                r.key_data() if r.key is None else self._sync(r.key)
+                for r in packed])))
+            sp.set(host_keys=sum(r.key is None for r in packed))
             ks = jax.vmap(lambda k: jax.random.split(k))(keys)  # (S, 2, 2)
             # re-commit the derived keys: vmap outputs lose the committed
             # layout, and an uncommitted jit operand lets GSPMD re-layout

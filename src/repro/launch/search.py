@@ -260,8 +260,9 @@ def serve(args, ws: WorkloadSet, mesh) -> int:
     per = spans.counters(snap)
     if per:
         print(f"[serve] per launch: {per['syncs']:.1f} reads, "
-              f"{per['bytes']:.0f} bytes harvested; pack hit both caches "
-              f"in {per['pack_hit']:.0%} of launches")
+              f"{per['bytes']:.0f} bytes harvested, "
+              f"{per['host_keys']:.0%} of slots keyed on the host; pack "
+              f"hit both caches in {per['pack_hit']:.0%} of launches")
     if cache is not None:
         print(f"[serve] cache: {stats.cache_hits} submit hits / "
               f"{stats.cache_misses} misses this drain "

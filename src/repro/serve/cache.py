@@ -93,7 +93,7 @@ def request_key(req: SearchRequest) -> str:
         req.backend, int(req.pop_size), int(req.generations),
         int(req.top_k), int(req.pareto_k), req.tech,
     )).encode())
-    h.update(np.asarray(req.prng_key()).tobytes())
+    h.update(req.key_data().tobytes())
     if req.init_genomes is not None:
         init = np.ascontiguousarray(np.asarray(req.init_genomes, np.float32))
         h.update(repr(init.shape).encode())

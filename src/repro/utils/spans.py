@@ -29,7 +29,9 @@ The spans the DSE records (``core/engine.py``, ``serve/dse.py``):
                        ``slots``, ``reqs``, ``P``, ``G``, ``W``, ``syncs``
 ``dse.dispatch.pack``  slot-packed workload tensors, stacked tables and
                        objective operands; attr ``hit``
-``dse.dispatch.keys``  per-slot PRNG keys, their stack and split
+``dse.dispatch.keys``  per-slot PRNG keys, their stack and split; attr
+                       ``host_keys`` (slots keyed on the host from their
+                       seed, with no device read)
 ``dse.dispatch.seed``  initial populations (seeding program enqueue)
 ``dse.dispatch.ga``    the GA program enqueue (or the segment chain)
 ``dse.harvest``        ``SearchEngine.harvest``; attrs ``launch``,
@@ -208,12 +210,15 @@ def phase_ms(snap: Snapshot) -> Dict[str, float]:
 def counters(snap: Snapshot) -> Dict[str, float]:
     """Per-launch readings of the span counters in ``snap``: ``syncs``
     (blocking reads of a launch's dispatch and harvest), ``bytes`` (what
-    its harvest moved), both means, and ``pack_hit`` (the share of
-    launches whose pack hit both content caches)."""
+    its harvest moved), both means, ``pack_hit`` (the share of launches
+    whose pack hit both content caches) and ``host_keys`` (the mean
+    share of a launch's slots keyed on the host)."""
     n = len(snap.launches)
     if not n:
         return {}
-    out = {"syncs": 0.0, "bytes": 0.0, "pack_hit": 0.0}
+    out = {"syncs": 0.0, "bytes": 0.0, "pack_hit": 0.0, "host_keys": 0.0}
+    slots = {s.launch: s.attrs.get("slots") for s in snap.spans
+             if s.name == DISPATCH}
     for s in snap.spans:
         if s.name in (DISPATCH, "dse.harvest"):
             out["syncs"] += s.attrs.get("syncs", 0) / n
@@ -221,6 +226,9 @@ def counters(snap: Snapshot) -> Dict[str, float]:
             out["bytes"] += s.attrs.get("bytes", 0) / n
         elif s.name == "dse.dispatch.pack":
             out["pack_hit"] += bool(s.attrs.get("hit")) / n
+        elif s.name == "dse.dispatch.keys" and slots.get(s.launch):
+            out["host_keys"] += s.attrs.get("host_keys", 0) / (
+                slots[s.launch] * n)
     return out
 
 

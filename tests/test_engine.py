@@ -172,6 +172,97 @@ def test_plan_batch_slot_hints_round_up_never_down(ws):
     assert [p.slots for p in plans] == [2, 2]
 
 
+# ------------------------------------------------------- host-built keys
+EDGE_SEEDS = [0, 1, 2**31 - 1, 2**31, 3141592653, 2**32 - 1, 2**32, 2**40,
+              -1, -2**31, -2**31 - 1, np.int64(5), np.uint32(7), True]
+
+
+@pytest.mark.parametrize("seed", EDGE_SEEDS, ids=repr)
+def test_key_data_equals_prng_key(seed, monkeypatch):
+    """The host-built key is ``PRNGKey(seed)``'s dtype and bytes, and
+    builds it without calling ``PRNGKey``."""
+    want = np.asarray(jax.random.PRNGKey(seed))
+    monkeypatch.setattr(jax.random, "PRNGKey", None)  # any call raises
+    got = SearchRequest(ws=None, seed=seed).key_data()
+    assert got.dtype == want.dtype and got.shape == want.shape
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("setting", ["x64", "rbg", "int_beyond_int64"])
+def test_key_data_falls_back_off_the_formula(setting, monkeypatch):
+    """Where the formula's preconditions fail, the helper takes the
+    ``jax.random.PRNGKey`` path and gives what it gives."""
+    real, calls = jax.random.PRNGKey, []
+
+    def spy(seed):
+        calls.append(seed)
+        return real(seed)
+
+    monkeypatch.setattr(jax.random, "PRNGKey", spy)
+    if setting == "int_beyond_int64":
+        with pytest.raises(OverflowError):
+            SearchRequest(ws=None, seed=2**63).key_data()
+        assert calls == [2**63]
+        return
+    ctx = (jax.enable_x64(True) if setting == "x64"
+           else jax.default_prng_impl("rbg"))
+    with ctx:
+        got = SearchRequest(ws=None, seed=2**40 + 3).key_data()
+        want = np.asarray(real(2**40 + 3))
+    assert calls == [2**40 + 3]
+    assert got.dtype == want.dtype and got.tobytes() == want.tobytes()
+    # not the formula's [0, seed mod 2**32]: the settings change the key
+    assert got.tobytes() != np.array([0, 3], np.uint32).tobytes()
+
+
+def test_seed_only_pipelined_launch_reads_no_key(ws, monkeypatch):
+    """A seed-only pipelined launch keys its slots on the host: a read
+    inside ``dse.dispatch.keys`` raises, and the answers are bit-identical
+    to ``run_search`` under ``prng_key()`` and to the sequential engine."""
+    reqs = [dataclasses.replace(r, seed=s) for r, s in zip(
+        _mixed_requests(ws, 4), [3141592653, 2**32 + 5, -7, 11])]
+    seq = SearchEngine(max_slots=4).run(reqs)
+    # run_search passes an explicit key, which the engine reads back
+    refs = [run_search(
+        req.prng_key(), req.ws, objective=req.objective,
+        area_constr=req.area_constr, pop_size=req.pop_size,
+        generations=req.generations, top_k=req.top_k, backend=req.backend)
+        for req in reqs]
+
+    open_spans = []
+    real_span = engine_mod.spans.span
+
+    class _Tracked:
+        def __init__(self, name, **attrs):
+            self.name, self.cm = name, real_span(name, **attrs)
+
+        def __enter__(self):
+            open_spans.append(self.name)
+            return self.cm.__enter__()
+
+        def __exit__(self, *exc):
+            open_spans.pop()
+            return self.cm.__exit__(*exc)
+
+    real_sync = SearchEngine._sync
+
+    def guarded_sync(self, x):
+        assert "dse.dispatch.keys" not in open_spans, "key read in dispatch"
+        return real_sync(self, x)
+
+    monkeypatch.setattr(engine_mod.spans, "span", _Tracked)
+    monkeypatch.setattr(SearchEngine, "_sync", guarded_sync)
+    eng = SearchEngine(max_slots=4, pipelined=True)
+    plan = plan_batch(reqs, max_slots=4)[0]
+    pend = eng.dispatch(plan)
+    assert eng.syncs == 0  # keys on the host, seed check deferred
+    pip = eng.harvest(pend)
+    for a, b, ref in zip(pip, seq, refs):
+        for other in (b, ref):
+            np.testing.assert_array_equal(a.top_scores, other.top_scores)
+            np.testing.assert_array_equal(a.top_genomes, other.top_genomes)
+
+
 # ------------------------------------------------- heterogeneous parity
 def test_heterogeneous_table_batch_matches_run_search(ws):
     reqs = _mixed_requests(ws, 8, backend="table")

@@ -26,6 +26,7 @@ What this file pins:
   * **Satellites** — the ``_TABLES_MEMO`` LRU cap (env-tunable,
     eviction + rebuild) and ``ServiceStats`` None-not-NaN percentiles.
 """
+import dataclasses
 import json
 
 import jax
@@ -164,6 +165,24 @@ def test_request_key_stable_and_seed_equals_explicit_key(ws):
     c = SearchRequest(ws=a.ws, seed=999, key=jax.random.PRNGKey(3),
                       backend="table", pop_size=POP, generations=GENS)
     assert request_key(c) == request_key(a)  # key bytes, not the seed int
+
+
+@pytest.mark.parametrize("seed", [0, 7, 2**31, 3141592653, 2**32 + 1, -1])
+def test_seed_hashes_unchanged_by_host_keys(ws, seed, monkeypatch):
+    """``request_key`` and ``plan_key`` of a seed-only request hash the
+    host-built key: byte for byte what they hashed when they read
+    ``PRNGKey(seed)`` from the device, and what an explicit key hashes."""
+    req = _reqs(ws, 1, seed0=seed)[0]
+    explicit = dataclasses.replace(req, key=jax.random.PRNGKey(seed))
+
+    def hashes(r):
+        return request_key(r), plan_key(plan_batch([r], max_slots=4)[0])
+
+    host = hashes(req)
+    assert hashes(explicit) == host
+    monkeypatch.setattr(SearchRequest, "key_data",
+                        lambda self: np.asarray(self.prng_key()))
+    assert hashes(req) == host
 
 
 def test_request_key_excludes_scheduling_metadata(ws):

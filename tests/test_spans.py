@@ -11,6 +11,7 @@ and the service keep about their own work.
     ``syncs`` adds up to the reads ``_sync`` counted; every request
     record is stamped submit <= dispatch <= resolution.
 """
+import dataclasses
 import glob
 import threading
 import time
@@ -188,14 +189,16 @@ def test_snapshot_windows_by_dispatch_start_and_phase_means():
 def test_counters_per_launch():
     rec = Recorder()
     for launch, hit in ((1, True), (2, False)):
-        with rec.span("dse.dispatch", launch=launch) as sp:
+        with rec.span("dse.dispatch", launch=launch, slots=4) as sp:
             with rec.span("dse.dispatch.pack") as pk:
                 pk.set(hit=hit)
+            with rec.span("dse.dispatch.keys") as ks:
+                ks.set(host_keys=4 if hit else 1)
             sp.set(syncs=4)
         with rec.span("dse.harvest", launch=launch) as sp:
             sp.set(syncs=5, bytes=100 * launch)
     assert spans.counters(rec.snapshot()) == {
-        "syncs": 9.0, "bytes": 150.0, "pack_hit": 0.5}
+        "syncs": 9.0, "bytes": 150.0, "pack_hit": 0.5, "host_keys": 0.625}
     assert spans.counters(rec.snapshot(hi=0.0)) == {}
 
 
@@ -236,24 +239,26 @@ def test_pipelined_engine_run_spans_and_syncs(ws):
     _check_launches(snap, served=False)
     top = [s for s in snap.spans if s.name in ("dse.dispatch", "dse.harvest")]
     assert sum(s.attrs["syncs"] for s in top) == eng.syncs
+    # seed-only plans move no key bytes: every byte is a harvest's
     assert sum(s.attrs["bytes"] for s in top
-               if s.name == "dse.harvest") + sum(
-        8 * s.attrs["slots"] for s in top if s.name == "dse.dispatch") \
-        == eng.transfer_bytes
+               if s.name == "dse.harvest") == eng.transfer_bytes
     for s in snap.spans:
         if s.name == "dse.dispatch":
-            # one read per slot key, the seed check deferred to harvest
-            assert s.attrs["syncs"] == s.attrs["slots"] == 4
+            # keys built on the host, the seed check deferred to harvest
+            assert s.attrs["syncs"] == 0 and s.attrs["slots"] == 4
+        elif s.name == "dse.dispatch.keys":
+            assert s.attrs["host_keys"] == 4
         elif s.name == "dse.dispatch.pack":
             assert s.attrs["hit"] is True  # the warm run packed these
         elif s.name == "dse.harvest":
             # the seed check and the four thin fields
             assert s.attrs["syncs"] == 5
     per = spans.counters(snap)
-    assert per["syncs"] == eng.syncs / 2 == 9
+    assert per["syncs"] == eng.syncs / 2 == 5
     assert per["bytes"] == sum(s.attrs["bytes"] for s in top
                                if s.name == "dse.harvest") / 2
     assert per["pack_hit"] == 1.0
+    assert per["host_keys"] == 1.0
 
 
 def test_segmented_guard_reads_count_as_syncs(ws):
@@ -267,9 +272,10 @@ def test_segmented_guard_reads_count_as_syncs(ws):
     snap = spans.snapshot(t0)
     assert snap.launches == [plan.launch]
     by = {s.name: s for s in snap.spans}
-    # four key reads, the eager seed check, the seed guard and one guard
-    # per segment; then the four thin fields
-    assert by["dse.dispatch"].attrs["syncs"] == plan.slots + 2 + GENS
+    # the eager seed check, the seed guard and one guard per segment (the
+    # keys are built on the host); then the four thin fields
+    assert by["dse.dispatch"].attrs["syncs"] == 2 + GENS
+    assert by["dse.dispatch.keys"].attrs["host_keys"] == plan.slots
     assert by["dse.harvest"].attrs["syncs"] == 4
     assert by["dse.dispatch"].attrs["syncs"] + 4 == eng.syncs
     ref = SearchEngine(max_slots=4, pipelined=True).run(reqs)
@@ -277,17 +283,28 @@ def test_segmented_guard_reads_count_as_syncs(ws):
         assert (a.top_scores == b.top_scores).all()
 
 
-def test_key_reads_go_through_sync(ws):
-    """The per-slot key reads count as reads and bytes, and change no
+@pytest.mark.parametrize("explicit", [False, True], ids=["seed", "key"])
+def test_key_reads_go_through_sync(ws, explicit):
+    """An explicit key's per-slot read counts as a read and its bytes; a
+    seed's key is built on the host and read nowhere.  Neither changes a
     result."""
     reqs = _reqs(ws, 3, seed0=20)
+    if explicit:
+        reqs = [dataclasses.replace(r, key=jax.random.PRNGKey(r.seed))
+                for r in reqs]
     plan = plan_batch(reqs, max_slots=4)[0]
     eng = SearchEngine(max_slots=4)
-    before = eng.syncs
+    t0 = time.perf_counter()
     pend = eng.dispatch(plan)
-    # sequential dispatch: four key reads, then the eager seed check
-    assert eng.syncs - before == plan.slots + 1
-    assert eng.transfer_bytes >= 8 * plan.slots
+    # sequential dispatch: one read per explicit slot key, then the eager
+    # seed check (one int32 count a slot)
+    assert eng.syncs == (plan.slots if explicit else 0) + 1
+    assert eng.transfer_bytes == (8 if explicit else 0) * plan.slots \
+        + 4 * plan.slots
+    keys = [s for s in spans.records()[0]
+            if s.name == "dse.dispatch.keys" and s.start >= t0]
+    assert [s.attrs["host_keys"] for s in keys] == [
+        0 if explicit else plan.slots]
     assert pend.plan is plan and plan.launch is not None
     t0 = time.perf_counter()
     res = eng.harvest(pend)
