@@ -71,6 +71,15 @@ class ModelConfig:
     moe_every: int = 1  # MoE ffn on layers with (i % moe_every == moe_every-1)
     capacity_factor: float = 1.25
     moe_d_ff: int = 0  # expert hidden size; 0 -> d_ff
+    n_shared_experts: int = 0  # always-on experts of width moe_d_ff per MoE layer
+    first_k_dense: int = 0  # leading layers whose ffn is a dense MLP of width d_ff
+
+    # --- multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1) -----
+    kv_lora_rank: int = 0  # >0 -> MLA: keys and values through this latent
+    q_lora_rank: int = 0  # 0 -> queries projected from the hidden state directly
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # --- SSM (Mamba-2 / SSD) -------------------------------------------------
     ssm_state: int = 0
@@ -113,6 +122,17 @@ class ModelConfig:
     @property
     def is_encdec(self) -> bool:
         return self.encoder_layers > 0
+
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def model_stack(self) -> bool:
+        """Whether the JAX model (``repro.models``) implements this config.
+        Latent attention, shared experts and leading dense layers are read
+        by the IMC exporter (``repro.workloads.lm``) alone."""
+        return not (self.is_mla or self.n_shared_experts or self.first_k_dense)
 
     @property
     def supports_long_context(self) -> bool:
@@ -178,6 +198,13 @@ class ModelConfig:
             ]
         return [("attn", "mlp")]
 
+    def layer_kinds(self) -> List[Tuple[str, str]]:
+        """(mixer, ffn) of every decoder layer, in order: the period
+        repeated, the first ``first_k_dense`` ffns dense."""
+        kinds = self.layer_plan() * self.n_blocks
+        return [(m, "mlp" if i < self.first_k_dense and f == "moe" else f)
+                for i, (m, f) in enumerate(kinds)]
+
     @property
     def period(self) -> int:
         return len(self.layer_plan())
@@ -203,8 +230,17 @@ class ModelConfig:
         ) * d
         if self.qkv_bias:
             attn += (self.n_heads + 2 * self.n_kv_heads) * hd
+        if self.is_mla:
+            H, r = self.n_heads, self.kv_lora_rank
+            qk = self.qk_nope_head_dim + self.qk_rope_head_dim
+            q = (d * self.q_lora_rank + self.q_lora_rank + self.q_lora_rank * H * qk
+                 if self.q_lora_rank else d * H * qk)
+            attn = (q + d * (r + self.qk_rope_head_dim) + r  # kv_a, its norm
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)  # kv_b
+                    + H * self.v_head_dim * d)  # o_proj
         mlp = 3 * d * self.d_ff
-        moe = self.n_experts * 3 * d * self.moe_d_ff_ + d * self.n_experts
+        moe = ((self.n_experts + self.n_shared_experts) * 3 * d * self.moe_d_ff_
+               + d * self.n_experts)
         di, ns = self.d_inner, self.ssm_state
         mamba = (
             d * (2 * di + 2 * self.ssm_groups * ns + self.ssm_heads)  # in_proj
@@ -213,8 +249,8 @@ class ModelConfig:
             + di * d  # out_proj
         )
         per_layer = {"attn": attn, "mamba": mamba, "mlp": mlp, "moe": moe, "none": 0}
-        for mixer, ffn in self.layer_plan():
-            n += (per_layer[mixer] + per_layer[ffn] + 2 * d) * self.n_blocks
+        for mixer, ffn in self.layer_kinds():
+            n += per_layer[mixer] + per_layer[ffn] + 2 * d
         if self.is_encdec:
             # encoder self-attn+mlp plus decoder cross-attn
             n += self.encoder_layers * (attn + mlp + 2 * d)
@@ -227,7 +263,7 @@ class ModelConfig:
             return self.param_count()
         full_moe = self.n_experts * 3 * self.d_model * self.moe_d_ff_
         act_moe = self.topk * 3 * self.d_model * self.moe_d_ff_
-        n_moe_layers = sum(1 for _, f in self.layer_plan() if f == "moe") * self.n_blocks
+        n_moe_layers = sum(1 for _, f in self.layer_kinds() if f == "moe")
         return self.param_count() - n_moe_layers * (full_moe - act_moe)
 
     # ---------------------------------------------------------------- reduction
@@ -271,9 +307,11 @@ def get_config(name: str) -> ModelConfig:
 
 
 def list_configs() -> List[str]:
+    """The registered configs the JAX model stack runs (``model_stack``);
+    ``get_config`` also finds those only the IMC exporter reads."""
     if not _REGISTRY:
         _load_all()
-    return sorted(_REGISTRY)
+    return sorted(n for n, c in _REGISTRY.items() if c.model_stack)
 
 
 def _load_all() -> None:
@@ -289,4 +327,5 @@ def _load_all() -> None:
         jamba_52b,
         mixtral_8x7b,
         qwen3_moe_235b,
+        deepseek_v2_lite,
     )

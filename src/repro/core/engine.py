@@ -255,7 +255,8 @@ def _seed_rounds(key, feats, mask, pop_size, oversample, max_rounds, tech):
     Each round draws ``pop_size * oversample`` candidates, keeps those that
     fit and are V/f-valid, and scatters them into the next free pool slots;
     a ``lax.while_loop`` repeats until the pool is full or ``max_rounds``
-    is hit — the host only syncs once, on the final (pool, count)."""
+    is hit — the host only syncs once, on the final (pool, count).
+    Returns ``(pool, count, rounds)``: ``rounds`` is how many were drawn."""
     n_cand = pop_size * oversample
 
     def cond(st):
@@ -276,8 +277,8 @@ def _seed_rounds(key, feats, mask, pop_size, oversample, max_rounds, tech):
 
     pool0 = jnp.zeros((pop_size, space.N_GENES), jnp.float32)
     st = (key, pool0, jnp.int32(0), jnp.int32(0))
-    _, pool, count, _ = jax.lax.while_loop(cond, body, st)
-    return pool, count
+    _, pool, count, rounds = jax.lax.while_loop(cond, body, st)
+    return pool, count, rounds
 
 
 _SEED_STATICS = ("pop_size", "oversample", "max_rounds", "tech")
@@ -292,13 +293,16 @@ def _seed_jit(key, feats, mask, *, pop_size, oversample, max_rounds, tech):
 def _seed_batched_jit(keys, feats, mask, *, pop_size, oversample, max_rounds, tech):
     """keys (B, 2), feats (B, W, L, 6), mask (B, W, L).  Each element's
     largest workload is picked as a TRACED argmax+gather inside the
-    program — no host-side device sync before the seeding launch."""
+    program — no host-side device sync before the seeding launch.
+    Returns ``(pools, seeded)``: ``seeded`` (2, B) int32 stacks each
+    element's count and rounds, so one read fetches both."""
 
     def one(k, ft, mk):
         li = jnp.argmax(_workload_weights(ft, mk))
         return _seed_rounds(k, ft[li], mk[li], pop_size, oversample, max_rounds, tech)
 
-    return jax.vmap(one)(keys, feats, mask)
+    pools, counts, rounds = jax.vmap(one)(keys, feats, mask)
+    return pools, jnp.stack([counts, rounds])
 
 
 def _valid_vt_mask(tech: TechParams) -> np.ndarray:
@@ -395,12 +399,14 @@ def _seed_direct(key, cdf6, pop_size, tech):
 def _seed_direct_batched_jit(keys, cdf6, *, pop_size, tech):
     """keys (B, 2), cdf6 (B, n_cells) stacked per-slot feasible-cell CDFs
     (largest workload each, precomputed host-side and cached) feeding the
-    direct cell sampler."""
+    direct cell sampler.  Returns ``(pools, seeded)`` as the rejection
+    seeder does; the direct sampler draws one round."""
 
     def one(k, cdf):
         return _seed_direct(k, cdf, pop_size, tech)
 
-    return jax.vmap(one)(keys, cdf6)
+    pools, counts = jax.vmap(one)(keys, cdf6)
+    return pools, jnp.stack([counts, jnp.ones_like(counts)])
 
 
 def seed_population(
@@ -415,7 +421,7 @@ def seed_population(
     """Random init; designs failing the largest workload (or V/f-invalid)
     are discarded (paper Sec. III-C).  One jitted while-loop program."""
     wi = largest_workload_index(ws)
-    pool, count = _seed_jit(
+    pool, count, _ = _seed_jit(
         key, ws.feats[wi], ws.mask[wi],
         pop_size=int(pop_size), oversample=int(oversample),
         max_rounds=int(max_rounds), tech=tech,
@@ -453,12 +459,12 @@ def seed_population_batched(
         keys = place_batched(mesh, keys)
         feats = place_batched(mesh, feats)
         mask = place_batched(mesh, mask)
-    pools, counts = _seed_batched_jit(
+    pools, seeded = _seed_batched_jit(
         keys, feats, mask,
         pop_size=int(pop_size), oversample=int(oversample),
         max_rounds=int(max_rounds), tech=tech,
     )
-    counts = np.asarray(counts)
+    counts = np.asarray(seeded)[0]
     if counts.min() < pop_size:
         bad = int(np.argmin(counts))
         raise RuntimeError(
@@ -986,9 +992,11 @@ class _LaunchPrep:
     init: Any
     ctx: tuple
     eval_fn: Callable
-    # deferred seed-feasibility check (pipelined dispatch only): syncing
-    # the seeder's counts would serialize back-to-back dispatches, so the
-    # check moves to harvest time.  None when already verified eagerly.
+    # the seed-feasibility check (``_init_populations``): returns the
+    # seeded slots and their rounds.  Pipelined dispatch defers its read
+    # to harvest time, since syncing the seeder's counts would serialize
+    # back-to-back dispatches; otherwise it ran eagerly and returns what
+    # it read then.  None when no slot was seeded.
     seed_check: Optional[Callable] = None
 
 
@@ -1282,7 +1290,8 @@ class SearchEngine:
         completed results into the cache — the host half of ``execute``.
         Records a ``dse.harvest`` span under the launch's id: the wait for
         the outputs, the reads (and the deferred seed check), the
-        finalize."""
+        finalize; ``seed_slots`` and ``seed_rounds`` count the slots the
+        seeder filled and the rounds they drew."""
         reqs = pending.plan.requests
         s0, b0 = self.syncs, self.transfer_bytes
         with spans.span("dse.harvest", launch=pending.plan.launch) as sp:
@@ -1292,7 +1301,8 @@ class SearchEngine:
                      if x is not None])
             with spans.span("dse.harvest.sync"):
                 if pending.seed_check is not None:
-                    pending.seed_check()
+                    seed_slots, seed_rounds = pending.seed_check()
+                    sp.set(seed_slots=seed_slots, seed_rounds=seed_rounds)
                 if pending.results is not None:
                     finalize = lambda: pending.results  # noqa: E731
                 elif pending.pareto is not None:
@@ -1638,7 +1648,8 @@ class SearchEngine:
         if thin:
             # final epilogue rides back un-synced; harvest does the rest
             return PendingLaunch(
-                plan=plan, thin=ga_epilogue_batched(gh, sh, top_k=K))
+                plan=plan, thin=ga_epilogue_batched(gh, sh, top_k=K),
+                seed_check=prep.seed_check)
         results = [
             _finalize(
                 self._history_result(gh[i], sh[i]),
@@ -1646,7 +1657,8 @@ class SearchEngine:
             )
             for i, r in enumerate(reqs)
         ]
-        return PendingLaunch(plan=plan, results=results)
+        return PendingLaunch(plan=plan, results=results,
+                             seed_check=prep.seed_check)
 
     def _request_seed_cdf(self, req: SearchRequest) -> np.ndarray:
         """One request's feasible-cell CDF for the direct seeder (host
@@ -1689,11 +1701,15 @@ class SearchEngine:
         tables at hand, the rejection rounds are replaced by the direct
         feasible-cell sampler (``_seed_direct``).
 
-        Returns ``(init, check)``: ``check`` is ``None`` when feasibility
-        was verified here, or (with ``defer``, all-seeded slots only) a
-        callable that syncs the counts and raises the identical
-        ``RuntimeError`` later — the pipelined dispatch path's way of
-        keeping the seeder's count array off the critical host path."""
+        Returns ``(init, check)``: ``check`` is ``None`` when no slot was
+        seeded, else a callable that verifies the seeding and returns
+        ``(seeded slots, rounds they drew)``.  It reads the seeder's
+        counts and rounds in one ``_sync`` on its first call and raises a
+        ``RuntimeError`` for a slot short of ``pop_size``; later calls
+        return what the first read.  It runs here, except with ``defer``
+        (all-seeded slots only), where harvest runs it — the pipelined
+        dispatch path's way of keeping the read off the critical host
+        path."""
         r0 = packed[0]
         P = int(r0.pop_size)
         needs = [r.init_genomes is None for r in packed]
@@ -1702,35 +1718,39 @@ class SearchEngine:
             return place(init, pop_dim=1), None
         if self.direct_seed and tables is not None:
             cdf6 = place(self._stacked_seed_cdf(packed, r0.tech))
-            pools, counts = _seed_direct_batched_jit(
+            pools, seeded = _seed_direct_batched_jit(
                 k_seed, cdf6, pop_size=P, tech=r0.tech,
             )
         else:
-            pools, counts = _seed_batched_jit(
+            pools, seeded = _seed_batched_jit(
                 k_seed, feats, mask,
                 pop_size=P, oversample=64, max_rounds=8, tech=r0.tech,
             )
+        read: List[Tuple[int, int]] = []
 
-        def check(counts=counts):
-            c = self._sync(counts)
-            for i, (r, need) in enumerate(zip(packed, needs)):
-                if need and c[i] < P:
-                    raise RuntimeError(
-                        f"could not seed {P} valid designs for request {i} "
-                        f"(workloads {r.ws.names}; {int(c[i])} found)"
-                    )
+        def check() -> Tuple[int, int]:
+            if not read:
+                counts, rounds = self._sync(seeded)
+                for i, (r, need) in enumerate(zip(packed, needs)):
+                    if need and counts[i] < P:
+                        raise RuntimeError(
+                            f"could not seed {P} valid designs for request "
+                            f"{i} (workloads {r.ws.names}; {int(counts[i])} "
+                            "found)"
+                        )
+                read.append((sum(needs), int(rounds[np.asarray(needs)].sum())))
+            return read[0]
 
         if all(needs):
-            if defer:
-                return place(pools, pop_dim=1), check
-            check()
-            return place(pools, pop_dim=1), None
+            if not defer:
+                check()
+            return place(pools, pop_dim=1), check
         check()  # the override merge below syncs the pools anyway
         pools = self._sync(pools).copy()  # writable, for the overrides
         for i, r in enumerate(packed):
             if r.init_genomes is not None:
                 pools[i] = np.asarray(r.init_genomes)
-        return place(jnp.asarray(pools), pop_dim=1), None
+        return place(jnp.asarray(pools), pop_dim=1), check
 
 
 _DEFAULT_ENGINE: Optional[SearchEngine] = None

@@ -10,6 +10,14 @@ architectures exported as IMC workloads):
     python -m repro.launch.search --lm-workloads llama3.2-1b,mixtral-8x7b \
         --mode decode
 
+One IMC chip's share of a deployment (``workloads/lm.py``): DeepSeek-V2-
+Lite's MoE layers 1-4 with 8 of 64 experts (EP 8), decoding at a
+16,384-token context:
+
+    python -m repro.launch.search --lm-workloads deepseek-v2-lite \
+        --layers 1-4 --ep 8 --batch 1 --context 16384 --head-share 0 \
+        --backend table --area 300
+
 ``--search-mesh SxP`` lays the batched programs out over a 2-D
 (search, population) device mesh (on CPU-only hosts export
 ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` first; real
@@ -107,7 +115,12 @@ def build_workloads(args) -> WorkloadSet:
     if args.lm_workloads:
         for n in args.lm_workloads.split(","):
             cfg = get_config(n)
-            named.append((n, lm_workload(cfg, mode=args.mode, seq=args.seq)))
+            layers = (tuple(int(v) for v in args.layers.split("-"))
+                      if args.layers else None)
+            named.append((n, lm_workload(
+                cfg, mode=args.mode, layers=layers, ep=args.ep,
+                batch=args.batch, chunk=args.chunk, context=args.context,
+                head_share=args.head_share)))
     if not named:
         named = [(n, cnn_workload(n)) for n in PAPER_WORKLOADS]
     return pack_workloads(named)
@@ -262,7 +275,9 @@ def serve(args, ws: WorkloadSet, mesh) -> int:
         print(f"[serve] per launch: {per['syncs']:.1f} reads, "
               f"{per['bytes']:.0f} bytes harvested, "
               f"{per['host_keys']:.0%} of slots keyed on the host; pack "
-              f"hit both caches in {per['pack_hit']:.0%} of launches")
+              f"hit both caches in {per['pack_hit']:.0%} of launches"
+              + (f"; {per['seed_rounds']:.2f} seeding rounds a seeded slot"
+                 if "seed_rounds" in per else ""))
     if cache is not None:
         print(f"[serve] cache: {stats.cache_hits} submit hits / "
               f"{stats.cache_misses} misses this drain "
@@ -291,7 +306,23 @@ def main(argv=None) -> int:
     ap.add_argument("--workloads", default="", help="CNN names, comma-sep")
     ap.add_argument("--lm-workloads", default="", help="assigned arch ids")
     ap.add_argument("--mode", default="decode", choices=["decode", "prefill"])
-    ap.add_argument("--seq", type=int, default=256)
+    # one IMC chip's share of an LM deployment (workloads/lm.py)
+    ap.add_argument("--layers", default="", metavar="FIRST-LAST",
+                    help="--lm-workloads: the pipeline stage, decoder "
+                         "layers FIRST to LAST inclusive (default: all)")
+    ap.add_argument("--ep", type=int, default=1,
+                    help="--lm-workloads: expert-parallel degree; the chip "
+                         "holds n_experts/EP routed experts")
+    ap.add_argument("--batch", type=int, default=1,
+                    help="--lm-workloads: sequences a rank decodes a step")
+    ap.add_argument("--chunk", type=int, default=256,
+                    help="--lm-workloads --mode prefill: tokens a step")
+    ap.add_argument("--context", type=int, default=None,
+                    help="--lm-workloads: cached tokens (decode) or prompt "
+                         "prefix (prefill); adds the KV-cache row")
+    ap.add_argument("--head-share", type=float, default=1.0,
+                    help="--lm-workloads: share of the vocabulary's LM-head "
+                         "rows on the chip (0: none)")
     ap.add_argument(
         "--objective", default="ela",
         help="scalar objective family (ela/edp/e/l) or 'pareto' for "

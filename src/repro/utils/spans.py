@@ -35,7 +35,9 @@ The spans the DSE records (``core/engine.py``, ``serve/dse.py``):
 ``dse.dispatch.seed``  initial populations (seeding program enqueue)
 ``dse.dispatch.ga``    the GA program enqueue (or the segment chain)
 ``dse.harvest``        ``SearchEngine.harvest``; attrs ``launch``,
-                       ``syncs``, ``bytes``
+                       ``syncs``, ``bytes``, and where the seeder ran
+                       ``seed_slots`` (slots it filled) and
+                       ``seed_rounds`` (rounds they drew, summed)
 ``dse.harvest.wait``   ``block_until_ready`` on the launch's outputs
 ``dse.harvest.sync``   the device->host reads and the seed check
 ``dse.harvest.finalize`` host finalize and result-cache writes
@@ -211,24 +213,30 @@ def counters(snap: Snapshot) -> Dict[str, float]:
     """Per-launch readings of the span counters in ``snap``: ``syncs``
     (blocking reads of a launch's dispatch and harvest), ``bytes`` (what
     its harvest moved), both means, ``pack_hit`` (the share of launches
-    whose pack hit both content caches) and ``host_keys`` (the mean
-    share of a launch's slots keyed on the host)."""
+    whose pack hit both content caches), ``host_keys`` (the mean
+    share of a launch's slots keyed on the host) and, where some launch
+    seeded a slot, ``seed_rounds`` (the seeder's rounds a seeded slot)."""
     n = len(snap.launches)
     if not n:
         return {}
     out = {"syncs": 0.0, "bytes": 0.0, "pack_hit": 0.0, "host_keys": 0.0}
     slots = {s.launch: s.attrs.get("slots") for s in snap.spans
              if s.name == DISPATCH}
+    seeded = [0, 0]  # slots, rounds
     for s in snap.spans:
         if s.name in (DISPATCH, "dse.harvest"):
             out["syncs"] += s.attrs.get("syncs", 0) / n
         if s.name == "dse.harvest":
             out["bytes"] += s.attrs.get("bytes", 0) / n
+            seeded[0] += s.attrs.get("seed_slots", 0)
+            seeded[1] += s.attrs.get("seed_rounds", 0)
         elif s.name == "dse.dispatch.pack":
             out["pack_hit"] += bool(s.attrs.get("hit")) / n
         elif s.name == "dse.dispatch.keys" and slots.get(s.launch):
             out["host_keys"] += s.attrs.get("host_keys", 0) / (
                 slots[s.launch] * n)
+    if seeded[0]:
+        out["seed_rounds"] = seeded[1] / seeded[0]
     return out
 
 

@@ -194,9 +194,10 @@ def test_direct_seed_designs_fit_largest_workload(ws):
                         pop_size=64, generations=1, top_k=4, tech=TECH)
     cdf = eng._request_seed_cdf(req)
     keys = jnp.stack([jax.random.PRNGKey(s) for s in range(3)])
-    pools, counts = _seed_direct_batched_jit(
+    pools, seeded = _seed_direct_batched_jit(
         keys, jnp.asarray(np.stack([cdf] * 3)), pop_size=64, tech=TECH)
-    assert np.all(np.asarray(counts) == 64)
+    counts, rounds = np.asarray(seeded)
+    assert np.all(counts == 64) and np.all(rounds == 1)
     tables = build_tables_arrays(ws.feats, ws.mask)
     from repro.core.engine import largest_workload_index
 

@@ -114,25 +114,34 @@ def test_depthwise_maps_badly():
 
 # ----------------------------------------------------------------- LM export
 @pytest.mark.parametrize("arch", ["llama3.2-1b", "mixtral-8x7b", "mamba2-780m",
-                                  "whisper-medium", "jamba-v0.1-52b"])
+                                  "whisper-medium", "jamba-v0.1-52b",
+                                  "deepseek-v2-lite"])
 def test_lm_workload_export(arch):
+    """Weight rows have K, N >= 1 and M > 0; at decode a routed expert
+    sees its expected share of the step's tokens, batch * topk / E (so
+    not every expert fires for every token), and every other row the
+    batch."""
     from repro.configs.base import get_config
 
     cfg = get_config(arch)
-    layers = lm_workload(cfg, mode="decode")
-    assert len(layers) > 0
-    arr = np.asarray(layers, np.float64)
-    assert (arr[:, :3] >= 1).all()  # M, K, N positive
-    # decode mode: single-token presentations everywhere
-    assert arr[:, 0].max() <= max(1, cfg.topk or 1)
+    batch = 4
+    arr = np.asarray(lm_workload(cfg, mode="decode", batch=batch), np.float64)
+    assert len(arr) > 0
+    assert (arr[:, 1:3] >= 1).all() and (arr[:, 0] > 0).all()
+    n_expert_rows = 3 * cfg.n_experts * sum(
+        f == "moe" for _, f in cfg.layer_kinds())
+    expert = np.zeros(len(arr), bool)
+    if cfg.n_experts:
+        expert = np.isclose(arr[:, 0], batch * cfg.topk / cfg.n_experts)
+    assert expert.sum() == n_expert_rows
+    np.testing.assert_array_equal(arr[~expert, 0], batch)
 
 
 def test_lm_workload_prefill_scales_m():
     from repro.configs.base import get_config
 
     cfg = get_config("llama3.2-1b")
-    d = np.asarray(lm_workload(cfg, mode="decode"), np.float64)
-    p = np.asarray(lm_workload(cfg, mode="prefill", seq=128), np.float64)
+    p = np.asarray(lm_workload(cfg, mode="prefill", chunk=128), np.float64)
     assert p[:, 0].max() == 128
 
 

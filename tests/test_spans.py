@@ -202,6 +202,22 @@ def test_counters_per_launch():
     assert spans.counters(rec.snapshot(hi=0.0)) == {}
 
 
+def test_counters_seed_rounds_per_seeded_slot():
+    """Rounds a seeded slot, over every launch that seeded; no reading
+    where nothing was seeded."""
+    rec = Recorder()
+    for launch, (n, rounds) in enumerate(((4, 4), (4, 10), (0, 0)), 1):
+        with rec.span("dse.dispatch", launch=launch, slots=4) as sp:
+            sp.set(syncs=0)
+        with rec.span("dse.harvest", launch=launch) as sp:
+            if n:
+                sp.set(seed_slots=n, seed_rounds=rounds)
+    assert spans.counters(rec.snapshot())["seed_rounds"] == 14 / 8
+    last = [s for s in rec.snapshot().spans if s.name == "dse.dispatch"][-1]
+    alone = spans.counters(rec.snapshot(lo=last.start))
+    assert alone["syncs"] == 0 and "seed_rounds" not in alone
+
+
 # ----------------------------------------------------------------- profiler
 def test_span_lands_on_the_profiler_host_plane(tmp_path):
     from jax.profiler import ProfileData
@@ -253,6 +269,9 @@ def test_pipelined_engine_run_spans_and_syncs(ws):
         elif s.name == "dse.harvest":
             # the seed check and the four thin fields
             assert s.attrs["syncs"] == 5
+            # every slot seeded, each in one round or more
+            assert s.attrs["seed_slots"] == 4
+            assert s.attrs["seed_rounds"] >= 4
     per = spans.counters(snap)
     assert per["syncs"] == eng.syncs / 2 == 5
     assert per["bytes"] == sum(s.attrs["bytes"] for s in top
@@ -297,10 +316,10 @@ def test_key_reads_go_through_sync(ws, explicit):
     t0 = time.perf_counter()
     pend = eng.dispatch(plan)
     # sequential dispatch: one read per explicit slot key, then the eager
-    # seed check (one int32 count a slot)
+    # seed check (one int32 count and one int32 rounds a slot, one read)
     assert eng.syncs == (plan.slots if explicit else 0) + 1
     assert eng.transfer_bytes == (8 if explicit else 0) * plan.slots \
-        + 4 * plan.slots
+        + 8 * plan.slots
     keys = [s for s in spans.records()[0]
             if s.name == "dse.dispatch.keys" and s.start >= t0]
     assert [s.attrs["host_keys"] for s in keys] == [
