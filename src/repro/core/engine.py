@@ -786,6 +786,48 @@ class SearchRequest:
                 self.tech, shape, obj)
 
 
+_THREEFRY_ROTATIONS = ((13, 15, 26, 6), (17, 29, 16, 24))
+
+
+def threefry2x32(k0, k1, x0, x1):
+    """JAX's 20-round ``threefry2x32`` hash in numpy: uint32 arrays
+    ``(x0, x1)`` under key ``(k0, k1)``, broadcast together (array
+    arithmetic wraps mod 2**32 without a warning)."""
+    ks = (k0, k1, k0 ^ k1 ^ np.uint32(0x1BD11BDA))
+    x0, x1 = x0 + ks[0], x1 + ks[1]
+    for i in range(5):
+        for r in _THREEFRY_ROTATIONS[i % 2]:
+            x0 = x0 + x1
+            x1 = x0 ^ ((x1 << np.uint32(r)) | (x1 >> np.uint32(32 - r)))
+        x0 = x0 + ks[(i + 1) % 3]
+        x1 = x1 + ks[(i + 2) % 3] + np.uint32(i + 1)
+    return x0, x1
+
+
+# the split where the host formula does not hold: traced once per shape
+_device_split = jax.jit(jax.vmap(jax.random.split))
+
+
+def split_key_data(data: np.ndarray) -> Tuple[Any, bool]:
+    """``jax.vmap(jax.random.split)`` of stacked ``(S, 2)`` key data, as
+    ``(split, on_host)`` with ``split`` of shape ``(S, 2, 2)``.
+
+    Under the default ``threefry2x32`` implementation with
+    ``jax_threefry_partitionable`` on (JAX 0.9's defaults)
+    ``split(key)[i]`` is ``threefry2x32(key, (0, i))``, computed here in
+    numpy with no device op (tests/test_engine.py pins it bit for bit).
+    Any other implementation or setting, or key data that is not raw
+    uint32 pairs, takes the jitted device split, so the bits never rest
+    on the formula where it was not checked."""
+    if (data.dtype == np.uint32 and data.ndim == 2 and data.shape[1] == 2
+            and jax.config.jax_default_prng_impl == "threefry2x32"
+            and jax.config.jax_threefry_partitionable):
+        lo = np.arange(2, dtype=np.uint32)
+        bits = threefry2x32(data[:, :1], data[:, 1:], np.zeros_like(lo), lo)
+        return np.stack(bits, axis=-1), True
+    return _device_split(jnp.asarray(data)), False
+
+
 @dataclasses.dataclass
 class BatchPlan:
     """One XLA launch: ``len(requests)`` real searches slot-packed into
@@ -1378,20 +1420,22 @@ class SearchEngine:
                 eval_fn = _ctx_eval(INDEXED, 0.0, tech, backend)
 
         with spans.span("dse.dispatch.keys") as sp:
-            # host-side stack, ONE device transfer for the plan: a seed's
-            # key is built on the host (no device work, no read, so the
-            # dispatch never waits on the device); an explicit key is read
-            # back through ``_sync``
-            keys = place(jnp.asarray(np.stack([
+            # host-side stack and split, ONE device transfer per derived
+            # key: a seed's key is built on the host (no device work, no
+            # read, so the dispatch never waits on the device); an explicit
+            # key is read back through ``_sync``
+            ks, on_host = split_key_data(np.stack([
                 r.key_data() if r.key is None else self._sync(r.key)
-                for r in packed])))
-            sp.set(host_keys=sum(r.key is None for r in packed))
-            ks = jax.vmap(lambda k: jax.random.split(k))(keys)  # (S, 2, 2)
-            # re-commit the derived keys: vmap outputs lose the committed
-            # layout, and an uncommitted jit operand lets GSPMD re-layout
-            # the whole program (bit-parity with the meshless run requires
-            # the exact input placements the sharded drivers always used)
-            k_seed, k_ga = place(ks[:, 0]), place(ks[:, 1])
+                for r in packed]))  # (S, 2, 2)
+            sp.set(host_keys=sum(r.key is None for r in packed),
+                   host_split=len(packed) if on_host else 0)
+            # commit the derived keys: an uncommitted jit operand lets
+            # GSPMD re-layout the whole program (bit-parity with the
+            # meshless run requires the exact input placements the sharded
+            # search always used); meshless, the programs get device
+            # arrays, not numpy operands
+            commit = jax.device_put if mesh is None else place
+            k_seed, k_ga = commit(ks[:, 0]), commit(ks[:, 1])
 
         with spans.span("dse.dispatch.seed"):
             init, seed_check = self._init_populations(

@@ -274,7 +274,8 @@ def serve(args, ws: WorkloadSet, mesh) -> int:
     if per:
         print(f"[serve] per launch: {per['syncs']:.1f} reads, "
               f"{per['bytes']:.0f} bytes harvested, "
-              f"{per['host_keys']:.0%} of slots keyed on the host; pack "
+              f"{per['host_keys']:.0%} of slots keyed and "
+              f"{per['host_split']:.0%} split on the host; pack "
               f"hit both caches in {per['pack_hit']:.0%} of launches"
               + (f"; {per['seed_rounds']:.2f} seeding rounds a seeded slot"
                  if "seed_rounds" in per else ""))

@@ -29,9 +29,10 @@ The spans the DSE records (``core/engine.py``, ``serve/dse.py``):
                        ``slots``, ``reqs``, ``P``, ``G``, ``W``, ``syncs``
 ``dse.dispatch.pack``  slot-packed workload tensors, stacked tables and
                        objective operands; attr ``hit``
-``dse.dispatch.keys``  per-slot PRNG keys, their stack and split; attr
+``dse.dispatch.keys``  per-slot PRNG keys, their stack and split; attrs
                        ``host_keys`` (slots keyed on the host from their
-                       seed, with no device read)
+                       seed, with no device read), ``host_split`` (slots
+                       whose key was split on the host, with no device op)
 ``dse.dispatch.seed``  initial populations (seeding program enqueue)
 ``dse.dispatch.ga``    the GA program enqueue (or the segment chain)
 ``dse.harvest``        ``SearchEngine.harvest``; attrs ``launch``,
@@ -213,13 +214,15 @@ def counters(snap: Snapshot) -> Dict[str, float]:
     """Per-launch readings of the span counters in ``snap``: ``syncs``
     (blocking reads of a launch's dispatch and harvest), ``bytes`` (what
     its harvest moved), both means, ``pack_hit`` (the share of launches
-    whose pack hit both content caches), ``host_keys`` (the mean
-    share of a launch's slots keyed on the host) and, where some launch
+    whose pack hit both content caches), ``host_keys`` and ``host_split``
+    (the mean share of a launch's slots keyed, and whose key was split, on
+    the host) and, where some launch
     seeded a slot, ``seed_rounds`` (the seeder's rounds a seeded slot)."""
     n = len(snap.launches)
     if not n:
         return {}
-    out = {"syncs": 0.0, "bytes": 0.0, "pack_hit": 0.0, "host_keys": 0.0}
+    out = {"syncs": 0.0, "bytes": 0.0, "pack_hit": 0.0, "host_keys": 0.0,
+           "host_split": 0.0}
     slots = {s.launch: s.attrs.get("slots") for s in snap.spans
              if s.name == DISPATCH}
     seeded = [0, 0]  # slots, rounds
@@ -233,8 +236,8 @@ def counters(snap: Snapshot) -> Dict[str, float]:
         elif s.name == "dse.dispatch.pack":
             out["pack_hit"] += bool(s.attrs.get("hit")) / n
         elif s.name == "dse.dispatch.keys" and slots.get(s.launch):
-            out["host_keys"] += s.attrs.get("host_keys", 0) / (
-                slots[s.launch] * n)
+            for k in ("host_keys", "host_split"):
+                out[k] += s.attrs.get(k, 0) / (slots[s.launch] * n)
     if seeded[0]:
         out["seed_rounds"] = seeded[1] / seeded[0]
     return out

@@ -215,6 +215,59 @@ def test_key_data_falls_back_off_the_formula(setting, monkeypatch):
     assert got.tobytes() != np.array([0, 3], np.uint32).tobytes()
 
 
+def _key_data_case(case):
+    """Stacked ``(S, 2)`` uint32 key data for one split-parity case."""
+    if case.startswith("slots-"):
+        s = int(case.split("-")[1])
+        return np.random.default_rng(s).integers(
+            0, 2**32, (s, 2), dtype=np.uint32)
+    if case == "edge-seeds":
+        return np.stack([SearchRequest(ws=None, seed=s).key_data()
+                         for s in EDGE_SEEDS])
+    if case == "prngkey":
+        return np.stack([np.asarray(jax.random.PRNGKey(s))
+                         for s in (0, 42, 2**31 + 11, 3141592653)])
+    assert case == "prior-split"
+    return np.asarray(jax.random.split(jax.random.PRNGKey(7), 5))
+
+
+@pytest.mark.parametrize("case", [
+    "slots-1", "slots-3", "slots-64", "slots-256", "edge-seeds", "prngkey",
+    "prior-split"])
+def test_host_split_equals_vmapped_split(case, monkeypatch):
+    """The host split is ``jax.vmap(jax.random.split)``'s dtype and bits,
+    and runs no device split."""
+    data = _key_data_case(case)
+    want = np.asarray(jax.vmap(jax.random.split)(jnp.asarray(data)))
+    monkeypatch.setattr(engine_mod, "_device_split", None)  # any call raises
+    got, on_host = engine_mod.split_key_data(data)
+    assert on_host and isinstance(got, np.ndarray)
+    assert got.dtype == want.dtype and got.shape == want.shape == (
+        len(data), 2, 2)
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("setting", ["not-partitionable", "rbg"])
+def test_host_split_falls_back_off_the_formula(setting):
+    """Where the formula's preconditions fail, the split runs on the
+    device and still gives what ``jax.vmap(jax.random.split)`` gives."""
+    ctx = (jax.threefry_partitionable(False)
+           if setting == "not-partitionable" else jax.default_prng_impl("rbg"))
+    with ctx:
+        data = np.stack([np.asarray(jax.random.PRNGKey(s))
+                         for s in (0, 3, 2**31 + 5)])
+        got, on_host = engine_mod.split_key_data(data)
+        want = np.asarray(jax.vmap(jax.random.split)(jnp.asarray(data)))
+    assert not on_host
+    assert np.asarray(got).tobytes() == want.tobytes()
+    # the formula's bits differ here: the fallback was needed
+    if setting == "not-partitionable":
+        host = np.stack(engine_mod.threefry2x32(
+            data[:, :1], data[:, 1:], np.zeros(2, np.uint32),
+            np.arange(2, dtype=np.uint32)), axis=-1)
+        assert host.tobytes() != want.tobytes()
+
+
 def test_seed_only_pipelined_launch_reads_no_key(ws, monkeypatch):
     """A seed-only pipelined launch keys its slots on the host: a read
     inside ``dse.dispatch.keys`` raises, and the answers are bit-identical

@@ -193,12 +193,13 @@ def test_counters_per_launch():
             with rec.span("dse.dispatch.pack") as pk:
                 pk.set(hit=hit)
             with rec.span("dse.dispatch.keys") as ks:
-                ks.set(host_keys=4 if hit else 1)
+                ks.set(host_keys=4 if hit else 1, host_split=4 if hit else 0)
             sp.set(syncs=4)
         with rec.span("dse.harvest", launch=launch) as sp:
             sp.set(syncs=5, bytes=100 * launch)
     assert spans.counters(rec.snapshot()) == {
-        "syncs": 9.0, "bytes": 150.0, "pack_hit": 0.5, "host_keys": 0.625}
+        "syncs": 9.0, "bytes": 150.0, "pack_hit": 0.5, "host_keys": 0.625,
+        "host_split": 0.5}
     assert spans.counters(rec.snapshot(hi=0.0)) == {}
 
 
@@ -264,6 +265,7 @@ def test_pipelined_engine_run_spans_and_syncs(ws):
             assert s.attrs["syncs"] == 0 and s.attrs["slots"] == 4
         elif s.name == "dse.dispatch.keys":
             assert s.attrs["host_keys"] == 4
+            assert s.attrs["host_split"] == 4
         elif s.name == "dse.dispatch.pack":
             assert s.attrs["hit"] is True  # the warm run packed these
         elif s.name == "dse.harvest":
@@ -278,6 +280,7 @@ def test_pipelined_engine_run_spans_and_syncs(ws):
                                if s.name == "dse.harvest") / 2
     assert per["pack_hit"] == 1.0
     assert per["host_keys"] == 1.0
+    assert per["host_split"] == 1.0
 
 
 def test_segmented_guard_reads_count_as_syncs(ws):
@@ -295,6 +298,7 @@ def test_segmented_guard_reads_count_as_syncs(ws):
     # keys are built on the host); then the four thin fields
     assert by["dse.dispatch"].attrs["syncs"] == 2 + GENS
     assert by["dse.dispatch.keys"].attrs["host_keys"] == plan.slots
+    assert by["dse.dispatch.keys"].attrs["host_split"] == plan.slots
     assert by["dse.harvest"].attrs["syncs"] == 4
     assert by["dse.dispatch"].attrs["syncs"] + 4 == eng.syncs
     ref = SearchEngine(max_slots=4, pipelined=True).run(reqs)
@@ -302,37 +306,44 @@ def test_segmented_guard_reads_count_as_syncs(ws):
         assert (a.top_scores == b.top_scores).all()
 
 
-@pytest.mark.parametrize("explicit", [False, True], ids=["seed", "key"])
-def test_key_reads_go_through_sync(ws, explicit):
+@pytest.mark.parametrize("explicit,host_split", [
+    (False, True), (True, True), (False, False)],
+    ids=["seed", "key", "seed-device-split"])
+def test_key_reads_go_through_sync(ws, explicit, host_split):
     """An explicit key's per-slot read counts as a read and its bytes; a
-    seed's key is built on the host and read nowhere.  Neither changes a
+    seed's key is built on the host and read nowhere.  Every key is split
+    on the host, except off the threefry formula's settings, where the
+    device splits it and ``host_split`` reads 0.  None changes a
     result."""
     reqs = _reqs(ws, 3, seed0=20)
     if explicit:
         reqs = [dataclasses.replace(r, key=jax.random.PRNGKey(r.seed))
                 for r in reqs]
-    plan = plan_batch(reqs, max_slots=4)[0]
-    eng = SearchEngine(max_slots=4)
-    t0 = time.perf_counter()
-    pend = eng.dispatch(plan)
-    # sequential dispatch: one read per explicit slot key, then the eager
-    # seed check (one int32 count and one int32 rounds a slot, one read)
-    assert eng.syncs == (plan.slots if explicit else 0) + 1
-    assert eng.transfer_bytes == (8 if explicit else 0) * plan.slots \
-        + 8 * plan.slots
-    keys = [s for s in spans.records()[0]
-            if s.name == "dse.dispatch.keys" and s.start >= t0]
-    assert [s.attrs["host_keys"] for s in keys] == [
-        0 if explicit else plan.slots]
-    assert pend.plan is plan and plan.launch is not None
-    t0 = time.perf_counter()
-    res = eng.harvest(pend)
-    harv = [s for s in spans.records()[0]
-            if s.name == "dse.harvest" and s.start >= t0]
-    assert [s.launch for s in harv] == [plan.launch]
-    ref = SearchEngine(max_slots=4, pipelined=True).run(reqs)
-    for a, b in zip(res, ref):
-        assert (a.top_scores == b.top_scores).all()
+    with jax.threefry_partitionable(host_split):
+        plan = plan_batch(reqs, max_slots=4)[0]
+        eng = SearchEngine(max_slots=4)
+        t0 = time.perf_counter()
+        pend = eng.dispatch(plan)
+        # sequential dispatch: one read per explicit slot key, then the eager
+        # seed check (one int32 count and one int32 rounds a slot, one read)
+        assert eng.syncs == (plan.slots if explicit else 0) + 1
+        assert eng.transfer_bytes == (8 if explicit else 0) * plan.slots \
+            + 8 * plan.slots
+        keys = [s for s in spans.records()[0]
+                if s.name == "dse.dispatch.keys" and s.start >= t0]
+        assert [s.attrs["host_keys"] for s in keys] == [
+            0 if explicit else plan.slots]
+        assert [s.attrs["host_split"] for s in keys] == [
+            plan.slots if host_split else 0]
+        assert pend.plan is plan and plan.launch is not None
+        t0 = time.perf_counter()
+        res = eng.harvest(pend)
+        harv = [s for s in spans.records()[0]
+                if s.name == "dse.harvest" and s.start >= t0]
+        assert [s.launch for s in harv] == [plan.launch]
+        ref = SearchEngine(max_slots=4, pipelined=True).run(reqs)
+        for a, b in zip(res, ref):
+            assert (a.top_scores == b.top_scores).all()
 
 
 # ------------------------------------------------------------------ service
