@@ -363,10 +363,9 @@ def cache_run(quick: bool = False, verbose: bool = True) -> dict:
     assert svc.stats.launches == launches_cold, "hot drains launched GA work"
     assert svc.stats.cache_hits == warm_reps * n
 
-    # --- pipelined-resubmit measurement (the ISSUE-10 thin-result caching
-    # fix, recorded so tools/check_fused_gate.py --cache can gate it):
-    # a PIPELINED engine's thin full results must populate the cache, so
-    # an identical resubmit drains with zero new GA launches
+    # --- pipelined-resubmit measurement: a PIPELINED engine's thin full
+    # results must populate the cache, so an identical resubmit drains
+    # with zero new GA launches
     n_pipe = 32
     pcache = ResultCache(capacity=2 * n_pipe)
     peng = SearchEngine(pipelined=True)
@@ -435,13 +434,16 @@ def fault_smoke(n: int = 16) -> int:
             self.seen = set()
             self.injected = 0
 
-        def execute(self, plan, *, mesh=None):
+        def dispatch(self, plan, **kw):
             key = tuple(sorted(r.seed for r in plan.requests))
             if len(key) > 1 and key not in self.seen:
                 self.seen.add(key)
                 self.injected += 1
                 raise EngineFault(f"injected transient fault for {key}")
-            return self.inner.execute(plan, mesh=mesh)
+            return self.inner.dispatch(plan, **kw)
+
+        def harvest(self, pending):
+            return self.inner.harvest(pending)
 
     ws = pack_workloads([(nm, cnn_workload(nm)) for nm in PAPER_WORKLOADS])
     eng = ChunkLaunchFails(SearchEngine(max_slots=8))

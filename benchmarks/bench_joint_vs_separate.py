@@ -73,16 +73,15 @@ def run(seeds: int = 5, verbose: bool = True, mesh=None,
 
         results["mesh"] = describe(mesh)
 
-    # --fast: the PR-8 fast path (fused generation step + direct table
-    # seeding) for both figure programs.  The fused part is bit-neutral;
-    # direct seeding draws DIFFERENT (equally valid) initial populations,
-    # so the figure's statistics stay comparable but not bit-identical.
+    # --fast: direct table seeding for both figure programs.  It draws
+    # DIFFERENT (equally valid) initial populations, so the figure's
+    # statistics stay comparable but not bit-identical.
     engine = None
     if fast:
         from repro.core.engine import SearchEngine
 
         engine = SearchEngine(mesh=mesh, max_slots=max(64, seeds * W),
-                              fused=True, direct_seed=True)
+                              direct_seed=True)
 
     t0 = time.time()
     joint_keys = jnp.stack([jax.random.PRNGKey(s) for s in range(seeds)])
@@ -184,7 +183,7 @@ def main(argv=None) -> int:
     )
     ap.add_argument(
         "--fast", action="store_true",
-        help="fused generation step + direct table seeding for both "
+        help="direct table seeding for both "
              "programs (use with --backend table; different but equally "
              "valid seed pools, so statistics — not bits — match)",
     )

@@ -130,16 +130,15 @@ def run(quick: bool = False, verbose: bool = True, mesh=None,
 
 def run_fused(quick: bool = False, verbose: bool = True,
               densities=(1, 2, 3)) -> dict:
-    """The fast-path row: fused generation step + direct table seeding on
-    the separate-search config (B single-workload GAs, table backend) —
+    """The fast-path row: direct table seeding on the separate-search
+    config (B single-workload GAs, table backend) —
     the configuration the >=1M designs/s acceptance number is measured on
     — swept over grid densities to characterize table memory vs gather
     cost (``configure_grid``; density d inserts d-1 points per grid
     interval, so the joint design space grows ~d^9).
 
     The first density in ``densities`` (the baseline grid) provides the
-    row's top-level ``designs_per_s`` that ``tools/ci.sh bench-smoke``
-    gates against the unfused ``table`` row."""
+    row's top-level ``designs_per_s``."""
     import numpy as np
 
     from repro.core import space
@@ -167,7 +166,7 @@ def run_fused(quick: bool = False, verbose: bool = True,
 
     out = {
         "pop": POP, "gens": GENS, "searches": B, "backend": "table",
-        "config": "separate", "fused": True, "direct_seed": True,
+        "config": "separate", "direct_seed": True,
         "warm_reps": warm_reps, "paper_s_per_design": PAPER_S_PER_DESIGN,
         "densities": [],
     }
@@ -175,7 +174,7 @@ def run_fused(quick: bool = False, verbose: bool = True,
     try:
         for d in densities:
             space.configure_grid(d)
-            eng = SearchEngine(max_slots=B, fused=True, direct_seed=True)
+            eng = SearchEngine(max_slots=B, direct_seed=True)
 
             def go():
                 return batched_search(keys, feats, mask, names=names,
@@ -212,7 +211,7 @@ def run_fused(quick: bool = False, verbose: bool = True,
                       f"{n/warm/1e6:.3f}M designs/s")
     finally:
         space.configure_grid(base_density)
-    # the gated steady-state number: the baseline grid's warm throughput
+    # the row's steady-state number: the baseline grid's warm throughput
     out.update({k: out["densities"][0][k]
                 for k in ("cold_s", "warm_s", "designs_per_s",
                           "speedup_vs_paper")})
@@ -222,7 +221,7 @@ def run_fused(quick: bool = False, verbose: bool = True,
 def run_pipelined(quick: bool = False, verbose: bool = True) -> dict:
     """The transfer-thin row: the SAME configuration as the ``fused`` row's
     baseline-grid entry (B = seeds x W separate searches, table backend,
-    fused generation step + direct table seeding) executed through a
+    direct table seeding) executed through a
     ``pipelined=True`` engine — the GA program computes its top-k-unique
     epilogue on device and only (B, top_k, n) genomes, (B, top_k) scores
     and (B, G+1) convergence cross the wire instead of the full (B, G+1,
@@ -230,8 +229,7 @@ def run_pipelined(quick: bool = False, verbose: bool = True) -> dict:
 
     Records warm designs/s plus host-transfer bytes per launch for BOTH
     the thin and the history-syncing engine (``transfer_reduction_x`` is
-    their ratio).  ``tools/check_fused_gate.py`` gates
-    ``designs_per_s >= fused row`` and ``transfer_reduction_x >= 10``."""
+    their ratio)."""
     import numpy as np
 
     from repro.core.engine import SearchEngine
@@ -255,9 +253,8 @@ def run_pipelined(quick: bool = False, verbose: bool = True) -> dict:
     mask = np.tile(np.asarray(ws.mask)[:, None], (seeds, 1, 1))
     names = [(w,) for w in PAPER_WORKLOADS] * seeds
 
-    thin = SearchEngine(max_slots=B, fused=True, direct_seed=True,
-                        pipelined=True)
-    hist = SearchEngine(max_slots=B, fused=True, direct_seed=True)
+    thin = SearchEngine(max_slots=B, direct_seed=True, pipelined=True)
+    hist = SearchEngine(max_slots=B, direct_seed=True)
 
     def go(eng):
         return batched_search(keys, feats, mask, names=names,
@@ -285,7 +282,7 @@ def run_pipelined(quick: bool = False, verbose: bool = True) -> dict:
 
     out = {
         "pop": POP, "gens": GENS, "searches": B, "backend": "table",
-        "config": "separate", "fused": True, "direct_seed": True,
+        "config": "separate", "direct_seed": True,
         "pipelined": True, "warm_reps": warm_reps,
         "paper_s_per_design": PAPER_S_PER_DESIGN,
         "cold_s": cold,
@@ -309,7 +306,7 @@ def run_pareto(quick: bool = False, verbose: bool = True,
                pareto_k: int = 10) -> dict:
     """The multi-objective row: NSGA-II Pareto-front search
     (``objective="pareto"``) on the fast-path configuration (B = seeds x W
-    separate searches, table backend, fused survival, direct seeding,
+    separate searches, table backend, direct seeding,
     transfer-thin pipelined engine).  Each search returns its ``pareto_k``
     best front members with per-member (E, L, A) vectors instead of one
     scalar optimum — this row tracks what the front search costs relative
@@ -337,8 +334,7 @@ def run_pareto(quick: bool = False, verbose: bool = True,
     mask = np.tile(np.asarray(ws.mask)[:, None], (seeds, 1, 1))
     names = [(w,) for w in PAPER_WORKLOADS] * seeds
 
-    eng = SearchEngine(max_slots=B, fused=True, direct_seed=True,
-                       pipelined=True)
+    eng = SearchEngine(max_slots=B, direct_seed=True, pipelined=True)
 
     def go():
         return batched_search(keys, feats, mask, names=names,
@@ -361,7 +357,7 @@ def run_pareto(quick: bool = False, verbose: bool = True,
     out = {
         "pop": POP, "gens": GENS, "searches": B, "backend": "table",
         "config": "separate", "objective": "pareto",
-        "pareto_k": int(pareto_k), "fused": True, "direct_seed": True,
+        "pareto_k": int(pareto_k), "direct_seed": True,
         "pipelined": True, "warm_reps": warm_reps,
         "paper_s_per_design": PAPER_S_PER_DESIGN,
         "cold_s": cold,
@@ -398,14 +394,14 @@ def main(argv=None) -> int:
     )
     ap.add_argument(
         "--fused", action="store_true",
-        help="run the fast-path config (fused gen step + direct table "
-             "seeding, separate-search B, table backend) over a grid-"
+        help="run the fast-path config (direct table seeding, "
+             "separate-search B, table backend) over a grid-"
              "density sweep and record the row under 'fused'",
     )
     ap.add_argument(
         "--grid-density", default="1,2,3", metavar="D[,D...]",
         help="comma-separated grid densities for the --fused sweep "
-             "(the first is the baseline the CI gate reads)",
+             "(the first gives the row's top-level numbers)",
     )
     ap.add_argument(
         "--pipelined", action="store_true",

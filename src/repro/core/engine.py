@@ -185,13 +185,6 @@ def _ctx_eval(
             return obj(r, ctx[-1])
         return obj(r)
 
-    if backend == "table" and objective == INDEXED:
-        # advertise the whole-generation Pallas kernel
-        # (repro.kernels.ga_gen_step): the kernel understands exactly this
-        # eval shape — factorized tables + traced (kind, area) tail — and
-        # reads the TechParams it must bake in from this marker.
-        eval_fn.gen_kernel_tech = tech
-
     return eval_fn
 
 
@@ -1101,11 +1094,12 @@ class SearchEngine:
         selection and convergence curve are computed ON DEVICE by the
         thin epilogue fused onto the GA program, so a launch syncs
         (S, K, n) genomes + (S, K) scores + (S, G+1) convergence instead
-        of the full (S, G+1, P, n) history, and ``execute`` splits into
-        ``dispatch``/``harvest`` so ``run()`` (and a pipelined service
-        drain) overlaps chunk i's host finalize with chunk i+1's device
-        compute.  Result fields are bit-identical to the sequential path
-        (tests/test_pipelined.py) EXCEPT ``SearchResult.ga`` is ``None``.
+        of the full (S, G+1, P, n) history, and ``run()`` dispatches
+        every chunk before harvesting the first (a pipelined service
+        drain keeps one launch in flight), so each chunk's host finalize
+        overlaps the remaining device compute.  Result fields are
+        bit-identical to the sequential path (tests/test_pipelined.py)
+        EXCEPT ``SearchResult.ga`` is ``None``.
         Thin FULL results are still result-cacheable — ``ResultCache``
         round-trips ``ga=None`` entries (only ``partial=True`` results
         are refused), so ``pipelined=True`` + ``result_cache`` resolves
@@ -1124,13 +1118,10 @@ class SearchEngine:
     def __init__(self, *, mesh=None, max_slots: int = 64,
                  segment_gens: Optional[int] = None, segment_retries: int = 1,
                  checkpoint_dir: Optional[str] = None, checkpoint_every: int = 1,
-                 result_cache=None, fused: Optional[bool] = None,
-                 direct_seed: bool = False, pipelined: bool = False):
+                 result_cache=None, direct_seed: bool = False,
+                 pipelined: bool = False):
         self.mesh = mesh
         self.max_slots = int(max_slots)
-        # fused: the GA survival-epilogue knob (None = ga.default_fused();
-        # both settings are bit-identical — see core.ga._make_gen_step)
-        self.fused = fused
         # direct_seed: table-backend-only inverse-CDF seeding (no rejection
         # rounds).  Same validity guarantees, DIFFERENT seed pools than the
         # rejection sampler, so it is opt-in: the default keeps every
@@ -1298,7 +1289,7 @@ class SearchEngine:
             prep = self._prepare(plan, mesh, defer_seed=self.pipelined)
             self.launches += 1
             kw = dict(pop_size=r0.pop_size, generations=r0.generations,
-                      init_genomes=prep.init, ctx=prep.ctx, fused=self.fused,
+                      init_genomes=prep.init, ctx=prep.ctx,
                       top_k=max(int(r.pareto_k) for r in plan.requests))
             with spans.span("dse.dispatch.ga"):
                 if self.pipelined:
@@ -1316,7 +1307,7 @@ class SearchEngine:
         prep = self._prepare(plan, mesh, defer_seed=self.pipelined)
         self.launches += 1
         kw = dict(pop_size=r0.pop_size, generations=r0.generations,
-                  init_genomes=prep.init, ctx=prep.ctx, fused=self.fused)
+                  init_genomes=prep.init, ctx=prep.ctx)
         with spans.span("dse.dispatch.ga"):
             if self.pipelined:
                 thin = run_ga_batched_thin(
@@ -1628,7 +1619,6 @@ class SearchEngine:
                         new_state, (hg, hs) = run_ga_batched_segment(
                             state, prep.eval_fn, ctx=prep.ctx,
                             generations=k_gens, total_generations=G,
-                            fused=self.fused,
                         )
                     if thin:
                         # guard on ONE reduced byte; the history stays put

@@ -38,10 +38,9 @@ tests/test_ga_segments.py and as a hypothesis property.
 """
 from __future__ import annotations
 
-import os
 import warnings
 from functools import partial
-from typing import Any, Callable, NamedTuple, Optional, Tuple
+from typing import Any, Callable, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,35 +51,6 @@ from repro.core.objectives import pareto_scalar
 SBX_PROB = 0.95
 SBX_ETA = 3.0
 MUT_ETA = 3.0
-
-
-def _env_flag(name: str, default: bool) -> bool:
-    v = os.environ.get(name)
-    if v is None:
-        return default
-    return v.strip().lower() not in ("", "0", "false", "off", "no")
-
-
-def default_fused() -> bool:
-    """Default for the ``fused`` GA knob: collapse the survival epilogue
-    (total-order keying + argsort + score gather) into ONE combined
-    ``lax.sort`` pass per generation.  On by default; set
-    ``REPRO_GA_FUSED=0`` to fall back to the two-pass epilogue.  Both
-    paths are bit-identical (the combined sort carries the scores through
-    the exact permutation ``_survivor_indices`` computes) — the flag only
-    trades program shape, never trajectories."""
-    return _env_flag("REPRO_GA_FUSED", True)
-
-
-def gen_kernel_enabled() -> bool:
-    """Opt-in for the Pallas whole-generation kernel
-    (``repro.kernels.ga_gen_step``).  Read at TRACE time: set
-    ``REPRO_GA_KERNEL=1`` before the first GA launch of the process (a
-    cached jit compiled with the flag off will not retrace).  Off by
-    default, and usable in interpret mode only: the TPU compiler refuses
-    the kernel (tests/test_tpu_compile.py pins the refusal), so a TPU run
-    with the flag set fails at its first launch."""
-    return _env_flag("REPRO_GA_KERNEL", False)
 
 
 class GAResult(NamedTuple):
@@ -159,10 +129,10 @@ class _IgnoreCtx:
 
 
 def _survivor_indices(scores: jnp.ndarray, k: int) -> jnp.ndarray:
-    """Indices of the k lowest scores, best-first, replacing the float
-    ``jnp.argsort(alls)[:P]`` of the survival step (the GA's post-PR-3
-    hot spot: survival touches 2P candidates per generation, all through
-    a stability-tracking float comparator).
+    """Indices of the k lowest scores, best-first: exactly the stable
+    ascending ``jnp.argsort(scores)[:k]``, without its stability-tracking
+    float comparator.  The reference permutation ``_elitist_survival``
+    applies in one combined sort.
 
     Implementation: one ``lax.sort`` over an integer key pair — the
     float32 score mapped to its total-order int32 (negatives: descending
@@ -350,8 +320,46 @@ def _poly_mutation(key, x: jnp.ndarray, eta: float, prob: float):
     return jnp.clip(jnp.where(do, x + delta, x), 0.0, 1.0 - 1e-7)
 
 
+def _elitist_survival(pop, scores, children, child_scores):
+    """(mu + lambda) elitist survival: the P best of parents + children,
+    best-first, as ``(survivors (P, n), their scores (P,))``.
+
+    One combined ``lax.sort`` over ``(folded score, candidate index,
+    score)``: the sign-folded total-order key (``_fold_bits``) tie-broken
+    by the index is a unique total order, so the permutation is exactly
+    ``_survivor_indices``' stable ascending argsort, and the scores ride
+    it instead of being gathered in a second pass (asserted against that
+    reference in tests/test_fused_gen.py)."""
+    P = pop.shape[0]
+    allg = jnp.concatenate([pop, children], axis=0)
+    alls = jnp.concatenate([scores, child_scores], axis=0)
+    okey = _fold_bits(alls)
+    iota = jax.lax.iota(jnp.int32, 2 * P)
+    _, idx, srt = jax.lax.sort((okey, iota, alls), num_keys=2,
+                               is_stable=False)
+    return allg[idx[:P]], srt[:P]
+
+
+def _crowded_survival(pop, objs, children, child_objs):
+    """NSGA-II survival: the P best of parents + children in crowded
+    order (ascending non-domination rank, descending crowding, candidate
+    index), as ``(survivors (P, n), their objective vectors (P, M))``.
+    The objective columns ride the ``(rank, ckey, index)`` sort, which is
+    a unique total order, so they equal a gather by the sorted index
+    (tests/test_fused_gen.py)."""
+    P = pop.shape[0]
+    allg = jnp.concatenate([pop, children], axis=0)
+    allo = jnp.concatenate([objs, child_objs], axis=0)
+    rank, ckey = _crowded_order_keys(allo)
+    iota = jax.lax.iota(jnp.int32, 2 * P)
+    cols = tuple(allo[:, m] for m in range(allo.shape[-1]))
+    srt = jax.lax.sort((rank, ckey, iota) + cols, num_keys=3,
+                       is_stable=False)
+    return allg[srt[2][:P]], jnp.stack(srt[3:], axis=-1)[:P]
+
+
 def _make_gen_step(eval_fn, ctx, pop_size, n_genes, sbx_prob, sbx_eta, mut_eta,
-                   fused=True, pareto=False):
+                   pareto=False):
     """The per-generation scan body, shared verbatim by the single-shot
     ``_ga_core`` and the segmented ``_segment_core`` so both paths compile
     the exact same generation program (the bit-parity guarantee).
@@ -361,29 +369,13 @@ def _make_gen_step(eval_fn, ctx, pop_size, n_genes, sbx_prob, sbx_eta, mut_eta,
     the same uniform block): the carry becomes ``(pop, objs (P, M),
     sel)`` where ``sel`` is the crowded-comparison position each
     tournament compares instead of a scalar score, ``eval_fn`` returns
-    (P, M) objective vectors, and survival replaces the (mu + lambda)
-    scalar sort with NSGA-II (rank, crowding) selection over the same
-    combined-``lax.sort`` machinery — ``fused`` carries the objective
-    columns through the sort, unfused gathers them by the sorted index;
-    both apply the identical permutation.  The Pallas whole-generation
-    kernel only understands scalar scores, so the kernel hook is gated
-    off under ``pareto``.
+    (P, M) objective vectors, and survival is ``_crowded_survival``
+    instead of ``_elitist_survival``.
 
     All per-generation randomness comes from ONE uniform block sliced at
     static offsets — the many small threefry launches of the original
     select/SBX/mutate splits carried fixed dispatch overheads that
-    dominated the generation on CPU.  ``fused`` only switches the survival
-    epilogue: ``True`` sorts ``(okey, iota, scores)`` in one combined
-    ``lax.sort`` pass (the scores ride the key permutation, saving the
-    separate score gather and its HBM round-trip); ``False`` keeps the
-    two-pass ``_survivor_indices`` + gather.  The sort keys are a unique
-    total order, so both epilogues apply the identical permutation —
-    fused vs unfused is pinned bit-identical in tests/test_fused_gen.py.
-
-    When ``REPRO_GA_KERNEL`` is set and the eval fn advertises table-gather
-    support (``gen_kernel_tech``), the whole generation instead lowers to
-    the Pallas kernel in ``repro.kernels.ga_gen_step`` (same bits, one
-    kernel launch per generation)."""
+    dominated the generation on CPU."""
     P = pop_size
     n = n_genes
     mut_prob = 1.0 / n
@@ -399,17 +391,6 @@ def _make_gen_step(eval_fn, ctx, pop_size, n_genes, sbx_prob, sbx_eta, mut_eta,
     o_mu = o_g + P * n           # mutation u
     o_md = o_mu + P * n          # mutation per-gene gate
     tot = o_md
-
-    if fused and not pareto and gen_kernel_enabled() \
-            and getattr(eval_fn, "gen_kernel_tech", None) is not None:
-        from repro.kernels.ga_gen_step import make_kernel_gen_step
-
-        kgen = make_kernel_gen_step(
-            eval_fn, ctx, pop_size=P, n_genes=n,
-            sbx_prob=sbx_prob, sbx_eta=sbx_eta, mut_eta=mut_eta,
-        )
-        if kgen is not None:
-            return kgen
 
     def gen(carry, k):
         if pareto:
@@ -453,40 +434,14 @@ def _make_gen_step(eval_fn, ctx, pop_size, n_genes, sbx_prob, sbx_eta, mut_eta,
             jnp.where(do, children + delta, children), 0.0, 1.0 - 1e-7)
         child_scores = eval_fn(children, ctx)
         if pareto:
-            # NSGA-II survival: (rank, crowding) over the 2P candidates
-            allg = jnp.concatenate([pop, children], axis=0)
-            allo = jnp.concatenate([objs, child_scores], axis=0)
-            rank, ckey = _crowded_order_keys(allo)
-            iota = jax.lax.iota(jnp.int32, 2 * P)
-            if fused:
-                cols = tuple(allo[:, m] for m in range(allo.shape[-1]))
-                srt = jax.lax.sort((rank, ckey, iota) + cols, num_keys=3,
-                                   is_stable=False)
-                idx = srt[2]
-                new_pop = allg[idx[:P]]
-                new_objs = jnp.stack(srt[3:], axis=-1)[:P]
-            else:
-                _, _, idx = jax.lax.sort((rank, ckey, iota), num_keys=3,
-                                         is_stable=False)
-                new_pop, new_objs = allg[idx[:P]], allo[idx[:P]]
+            new_pop, new_objs = _crowded_survival(pop, objs, children,
+                                                  child_scores)
             # survival order == crowded order, so the next tournament's
             # selection key is just the position
             new_sel = jax.lax.iota(jnp.int32, P).astype(jnp.float32)
             return (new_pop, new_objs, new_sel), (children, child_scores)
-        # (mu + lambda) elitist survival
-        allg = jnp.concatenate([pop, children], axis=0)
-        alls = jnp.concatenate([scores, child_scores], axis=0)
-        if fused:
-            bits = jax.lax.bitcast_convert_type(
-                alls.astype(jnp.float32), jnp.int32)
-            okey = jnp.where(bits < 0, -(bits & jnp.int32(0x7FFFFFFF)), bits)
-            iota = jax.lax.iota(jnp.int32, 2 * P)
-            _, idx, srt = jax.lax.sort(
-                (okey, iota, alls), num_keys=2, is_stable=False)
-            new_pop, new_scores = allg[idx[:P]], srt[:P]
-        else:
-            order = _survivor_indices(alls, P)
-            new_pop, new_scores = allg[order], alls[order]
+        new_pop, new_scores = _elitist_survival(pop, scores, children,
+                                                child_scores)
         return (new_pop, new_scores), (children, child_scores)
 
     return gen
@@ -494,12 +449,11 @@ def _make_gen_step(eval_fn, ctx, pop_size, n_genes, sbx_prob, sbx_eta, mut_eta,
 
 def _ga_core(
     key, eval_fn, pop_size, generations, init_genomes, ctx,
-    sbx_prob, sbx_eta, mut_eta, fused,
+    sbx_prob, sbx_eta, mut_eta,
 ) -> GAResult:
     n = init_genomes.shape[-1]
     s0 = eval_fn(init_genomes, ctx)
-    gen = _make_gen_step(eval_fn, ctx, pop_size, n, sbx_prob, sbx_eta, mut_eta,
-                         fused=fused)
+    gen = _make_gen_step(eval_fn, ctx, pop_size, n, sbx_prob, sbx_eta, mut_eta)
     keys = jax.random.split(key, generations)
     (pop, scores), (hist_g, hist_s) = jax.lax.scan(gen, (init_genomes, s0), keys)
 
@@ -517,7 +471,7 @@ def _ga_core(
 
 def _pareto_core(
     key, eval_fn, pop_size, generations, init_genomes, ctx,
-    sbx_prob, sbx_eta, mut_eta, fused,
+    sbx_prob, sbx_eta, mut_eta,
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The Pareto twin of ``_ga_core``: same master-key stream, same
     variation, NSGA-II survival.  ``eval_fn(genomes, ctx)`` must return
@@ -528,7 +482,7 @@ def _pareto_core(
     o0 = eval_fn(init_genomes, ctx)
     sel0 = _crowded_positions(o0)
     gen = _make_gen_step(eval_fn, ctx, pop_size, n, sbx_prob, sbx_eta,
-                         mut_eta, fused=fused, pareto=True)
+                         mut_eta, pareto=True)
     keys = jax.random.split(key, generations)
     _, (hist_g, hist_o) = jax.lax.scan(gen, (init_genomes, o0, sel0), keys)
     genomes_hist = jnp.concatenate([init_genomes[None], hist_g], axis=0)
@@ -538,7 +492,6 @@ def _pareto_core(
 
 def _segment_core(
     state, eval_fn, ctx, seg_gens, total_gens, sbx_prob, sbx_eta, mut_eta,
-    fused,
 ) -> Tuple[GAState, Tuple[jnp.ndarray, jnp.ndarray]]:
     """Advance ``seg_gens`` generations from ``state``.
 
@@ -551,8 +504,7 @@ def _segment_core(
     """
     pop, scores = state.genomes, state.scores
     P, n = pop.shape[-2], pop.shape[-1]
-    gen = _make_gen_step(eval_fn, ctx, P, n, sbx_prob, sbx_eta, mut_eta,
-                         fused=fused)
+    gen = _make_gen_step(eval_fn, ctx, P, n, sbx_prob, sbx_eta, mut_eta)
     all_keys = jax.random.split(state.key, total_gens)
     keys = jax.lax.dynamic_slice_in_dim(all_keys, state.gen, seg_gens)
     (pop, scores), hist = jax.lax.scan(gen, (pop, scores), keys)
@@ -564,24 +516,24 @@ def _segment_core(
 
 
 _GA_STATICS = ("eval_fn", "pop_size", "generations", "sbx_prob", "sbx_eta",
-               "mut_eta", "fused")
+               "mut_eta")
 _SEG_STATICS = ("eval_fn", "seg_gens", "total_gens", "sbx_prob", "sbx_eta",
-                "mut_eta", "fused")
+                "mut_eta")
 
 
 @partial(jax.jit, static_argnames=_GA_STATICS, donate_argnames=("init_genomes",))
 def _run_ga_jit(key, init_genomes, ctx, *, eval_fn, pop_size, generations,
-                sbx_prob, sbx_eta, mut_eta, fused):
+                sbx_prob, sbx_eta, mut_eta):
     return _ga_core(key, eval_fn, pop_size, generations, init_genomes, ctx,
-                    sbx_prob, sbx_eta, mut_eta, fused)
+                    sbx_prob, sbx_eta, mut_eta)
 
 
 @partial(jax.jit, static_argnames=_GA_STATICS, donate_argnames=("init_genomes",))
 def _run_ga_batched_jit(keys, init_genomes, ctx, *, eval_fn, pop_size,
-                        generations, sbx_prob, sbx_eta, mut_eta, fused):
+                        generations, sbx_prob, sbx_eta, mut_eta):
     def one(key, init, c):
         return _ga_core(key, eval_fn, pop_size, generations, init, c,
-                        sbx_prob, sbx_eta, mut_eta, fused)
+                        sbx_prob, sbx_eta, mut_eta)
 
     ctx_axes = jax.tree_util.tree_map(lambda _: 0, ctx)
     return jax.vmap(one, in_axes=(0, 0, ctx_axes))(keys, init_genomes, ctx)
@@ -607,17 +559,17 @@ def _init_state_batched_jit(keys, init_genomes, ctx, *, eval_fn):
 
 @partial(jax.jit, static_argnames=_SEG_STATICS)
 def _run_ga_segment_jit(state, ctx, *, eval_fn, seg_gens, total_gens,
-                        sbx_prob, sbx_eta, mut_eta, fused):
+                        sbx_prob, sbx_eta, mut_eta):
     return _segment_core(state, eval_fn, ctx, seg_gens, total_gens,
-                         sbx_prob, sbx_eta, mut_eta, fused)
+                         sbx_prob, sbx_eta, mut_eta)
 
 
 @partial(jax.jit, static_argnames=_SEG_STATICS)
 def _run_ga_batched_segment_jit(state, ctx, *, eval_fn, seg_gens, total_gens,
-                                sbx_prob, sbx_eta, mut_eta, fused):
+                                sbx_prob, sbx_eta, mut_eta):
     def one(st, c):
         return _segment_core(st, eval_fn, c, seg_gens, total_gens,
-                             sbx_prob, sbx_eta, mut_eta, fused)
+                             sbx_prob, sbx_eta, mut_eta)
 
     ctx_axes = jax.tree_util.tree_map(lambda _: 0, ctx)
     return jax.vmap(one, in_axes=(0, ctx_axes))(state, ctx)
@@ -634,12 +586,8 @@ def run_ga(
     sbx_prob: float = SBX_PROB,
     sbx_eta: float = SBX_ETA,
     mut_eta: float = MUT_ETA,
-    fused: Optional[bool] = None,
 ) -> GAResult:
     """Run the GA as one cached jit.  Lower score = better.
-
-    ``fused`` selects the combined-sort survival epilogue (bit-identical
-    to the unfused one); ``None`` means ``default_fused()``.
 
     ``eval_fn(genomes (P, n)) -> scores (P,)`` when ``ctx`` is ``None``, or
     ``eval_fn(genomes, ctx) -> scores`` with ``ctx`` an arbitrary pytree of
@@ -662,7 +610,6 @@ def run_ga(
             key, init_genomes, ctx,
             eval_fn=eval_fn, pop_size=int(pop_size), generations=int(generations),
             sbx_prob=float(sbx_prob), sbx_eta=float(sbx_eta), mut_eta=float(mut_eta),
-            fused=bool(default_fused() if fused is None else fused),
         )
 
 
@@ -677,7 +624,6 @@ def run_ga_batched(
     sbx_prob: float = SBX_PROB,
     sbx_eta: float = SBX_ETA,
     mut_eta: float = MUT_ETA,
-    fused: Optional[bool] = None,
 ) -> GAResult:
     """B independent GAs in one vmapped XLA program.
 
@@ -698,7 +644,6 @@ def run_ga_batched(
             keys, init_genomes, ctx,
             eval_fn=eval_fn, pop_size=int(pop_size), generations=int(generations),
             sbx_prob=float(sbx_prob), sbx_eta=float(sbx_eta), mut_eta=float(mut_eta),
-            fused=bool(default_fused() if fused is None else fused),
         )
 
 
@@ -737,7 +682,6 @@ def run_ga_segment(
     sbx_prob: float = SBX_PROB,
     sbx_eta: float = SBX_ETA,
     mut_eta: float = MUT_ETA,
-    fused: Optional[bool] = None,
 ) -> Tuple[GAState, Tuple[jnp.ndarray, jnp.ndarray]]:
     """Advance ``generations`` (k) generations through ONE cached jit,
     returning ``(new_state, (children (k, P, n), child_scores (k, P)))``.
@@ -755,7 +699,6 @@ def run_ga_segment(
         state, ctx, eval_fn=eval_fn,
         seg_gens=int(generations), total_gens=int(total_generations),
         sbx_prob=float(sbx_prob), sbx_eta=float(sbx_eta), mut_eta=float(mut_eta),
-        fused=bool(default_fused() if fused is None else fused),
     )
 
 
@@ -769,7 +712,6 @@ def run_ga_batched_segment(
     sbx_prob: float = SBX_PROB,
     sbx_eta: float = SBX_ETA,
     mut_eta: float = MUT_ETA,
-    fused: Optional[bool] = None,
 ) -> Tuple[GAState, Tuple[jnp.ndarray, jnp.ndarray]]:
     """Batched ``run_ga_segment``: state fields and ctx leaves carry a
     leading (B,) axis; histories come back as (B, k, P, n) / (B, k, P).
@@ -781,7 +723,6 @@ def run_ga_batched_segment(
         state, ctx, eval_fn=eval_fn,
         seg_gens=int(generations), total_gens=int(total_generations),
         sbx_prob=float(sbx_prob), sbx_eta=float(sbx_eta), mut_eta=float(mut_eta),
-        fused=bool(default_fused() if fused is None else fused),
     )
 
 
@@ -884,11 +825,10 @@ def _thin_epilogue(genomes_hist, scores_hist, top_k: int) -> GAThin:
 @partial(jax.jit, static_argnames=_GA_STATICS + ("top_k",),
          donate_argnames=("init_genomes",))
 def _run_ga_batched_thin_jit(keys, init_genomes, ctx, *, eval_fn, pop_size,
-                             generations, sbx_prob, sbx_eta, mut_eta, fused,
-                             top_k):
+                             generations, sbx_prob, sbx_eta, mut_eta, top_k):
     def one(key, init, c):
         ga = _ga_core(key, eval_fn, pop_size, generations, init, c,
-                      sbx_prob, sbx_eta, mut_eta, fused)
+                      sbx_prob, sbx_eta, mut_eta)
         return _thin_epilogue(ga.genomes, ga.scores, top_k)
 
     ctx_axes = jax.tree_util.tree_map(lambda _: 0, ctx)
@@ -914,7 +854,6 @@ def run_ga_batched_thin(
     sbx_prob: float = SBX_PROB,
     sbx_eta: float = SBX_ETA,
     mut_eta: float = MUT_ETA,
-    fused: Optional[bool] = None,
 ) -> GAThin:
     """``run_ga_batched`` with the thin epilogue fused onto the SAME
     program: one donated jit runs B GAs and reduces each full history to
@@ -936,7 +875,6 @@ def run_ga_batched_thin(
             eval_fn=eval_fn, pop_size=int(pop_size),
             generations=int(generations), sbx_prob=float(sbx_prob),
             sbx_eta=float(sbx_eta), mut_eta=float(mut_eta),
-            fused=bool(default_fused() if fused is None else fused),
             top_k=int(top_k),
         )
 
@@ -1059,11 +997,11 @@ _PARETO_STATICS = _GA_STATICS + ("top_k", "history")
 @partial(jax.jit, static_argnames=_PARETO_STATICS,
          donate_argnames=("init_genomes",))
 def _run_pareto_batched_jit(keys, init_genomes, ctx, *, eval_fn, pop_size,
-                            generations, sbx_prob, sbx_eta, mut_eta, fused,
-                            top_k, history):
+                            generations, sbx_prob, sbx_eta, mut_eta, top_k,
+                            history):
     def one(key, init, c):
         gh, oh = _pareto_core(key, eval_fn, pop_size, generations, init, c,
-                              sbx_prob, sbx_eta, mut_eta, fused)
+                              sbx_prob, sbx_eta, mut_eta)
         thin = _pareto_epilogue(gh, oh, top_k)
         if history:
             return gh, oh, thin
@@ -1092,7 +1030,6 @@ def run_pareto_batched(
     sbx_prob: float = SBX_PROB,
     sbx_eta: float = SBX_ETA,
     mut_eta: float = MUT_ETA,
-    fused: Optional[bool] = None,
     history: bool = False,
 ):
     """B independent NSGA-II Pareto searches in one vmapped, donated XLA
@@ -1106,8 +1043,7 @@ def run_pareto_batched(
     partials) additionally returns ``(genomes_hist (B, G+1, P, n),
     objs_hist (B, G+1, P, M))``.  Both run the IDENTICAL program prefix
     and epilogue, so the selected front members are bit-identical across
-    the two modes, and across ``fused``/unfused survival (same sort
-    permutation — tests/test_pareto.py)."""
+    the two modes (tests/test_pareto.py)."""
     if ctx is None and not isinstance(eval_fn, _IgnoreCtx):
         eval_fn = _IgnoreCtx(eval_fn)
     with warnings.catch_warnings():
@@ -1119,7 +1055,6 @@ def run_pareto_batched(
             eval_fn=eval_fn, pop_size=int(pop_size),
             generations=int(generations), sbx_prob=float(sbx_prob),
             sbx_eta=float(sbx_eta), mut_eta=float(mut_eta),
-            fused=bool(default_fused() if fused is None else fused),
             top_k=int(top_k), history=bool(history),
         )
 

@@ -87,20 +87,19 @@ from repro.imc.tech import TECH, TechParams
 from repro.workloads.pack import WorkloadSet
 
 
-def _resolve_engine(engine, fused, pipelined=None):
+def _resolve_engine(engine, pipelined=None):
     """The engine a driver call runs on: an explicit ``engine`` wins (its
-    own ``fused``/``pipelined`` settings govern), otherwise the shared
-    default — or, when the caller pins ``fused`` or ``pipelined``, a
-    per-call engine carrying the flags (engines are stateless apart from
-    content caches, so this costs one object, not a retrace: the jit
-    caches are global)."""
+    own ``pipelined`` setting governs), otherwise the shared default — or,
+    when the caller pins ``pipelined``, a per-call engine carrying it
+    (engines are stateless apart from content caches, so this costs one
+    object, not a retrace: the jit caches are global)."""
     if engine is not None:
         return engine
-    if fused is None and pipelined is None:
+    if pipelined is None:
         return default_engine()
     from repro.core.engine import SearchEngine
 
-    return SearchEngine(fused=fused, pipelined=bool(pipelined))
+    return SearchEngine(pipelined=bool(pipelined))
 
 
 # ----------------------------------------------------------------- drivers
@@ -118,20 +117,16 @@ def run_search(
     tech: TechParams = TECH,
     backend: str = "jnp",
     engine=None,
-    fused: Optional[bool] = None,
     pipelined: Optional[bool] = None,
 ) -> SearchResult:
     """One joint search = a single-request engine plan.  ``engine``
     substitutes a configured ``SearchEngine`` (e.g. segmented execution
-    with checkpoints) for the shared default.  ``fused`` pins the GA
-    survival-epilogue mode (None = the process default; both settings are
-    bit-identical — it only changes the compiled program shape).
-    ``pipelined`` pins the transfer-thin engine path: identical result
-    fields, but ``result.ga`` is ``None`` (the history stays on device —
-    see ``SearchEngine``).  ``objective="pareto"`` switches to NSGA-II
-    front search: the result's ``top_*`` fields hold the ``pareto_k``
-    best front members in crowded order and ``objective_vectors`` their
-    per-member (E, L, A) triples."""
+    with checkpoints) for the shared default.  ``pipelined`` pins the
+    transfer-thin engine path: identical result fields, but ``result.ga``
+    is ``None`` (the history stays on device — see ``SearchEngine``).
+    ``objective="pareto"`` switches to NSGA-II front search: the result's
+    ``top_*`` fields hold the ``pareto_k`` best front members in crowded
+    order and ``objective_vectors`` their per-member (E, L, A) triples."""
     req = SearchRequest(
         ws=ws, objective=objective, area_constr=float(area_constr),
         key=key, backend=backend, pop_size=int(pop_size),
@@ -139,7 +134,7 @@ def run_search(
         pareto_k=int(pareto_k), tech=tech,
         init_genomes=init_genomes,
     )
-    return _resolve_engine(engine, fused, pipelined).run([req])[0]
+    return _resolve_engine(engine, pipelined).run([req])[0]
 
 
 def joint_search(key, ws: WorkloadSet, **kw) -> SearchResult:
@@ -164,7 +159,6 @@ def batched_search(
     backend: str = "jnp",
     mesh=None,
     engine=None,
-    fused: Optional[bool] = None,
     pipelined: Optional[bool] = None,
 ) -> List[SearchResult]:
     """B independent searches through the engine (one plan when shapes
@@ -221,7 +215,7 @@ def batched_search(
         )
         for b in range(B)
     ]
-    return _resolve_engine(engine, fused, pipelined).run(reqs, mesh=mesh)
+    return _resolve_engine(engine, pipelined).run(reqs, mesh=mesh)
 
 
 def joint_search_batched(keys: jnp.ndarray, ws: WorkloadSet, **kw) -> List[SearchResult]:

@@ -10,9 +10,11 @@ through the shared ``core.engine.SearchEngine``:
     requests get their factorized cost tables built (fingerprint-memoized)
     at ingest, the way the LM engine prefills on admission — the drain
     itself then launches only the cached seeding + GA programs.
-  * ``step``    — execute ONE plan (one XLA launch) of the current queue;
-    finished results free their slots immediately and newly submitted
-    requests join the next step's packing.
+  * ``step``    — run ONE launch cycle: dispatch the next plan of the
+    current queue and harvest the launch in flight (with ``pipelined``,
+    the previous one; otherwise this one); finished results free their
+    slots immediately and newly submitted requests join the next step's
+    packing.
   * ``drain``   — step until the queue is empty; returns {rid: result}.
   * ``stream``  — generator form of drain: yields (rid, SearchResult) per
     completed plan, so callers consume results while later plans run.
@@ -25,12 +27,12 @@ absolute deadline on the service clock at ingest).  The policy reorders
 the queue and the launch order; it never changes which compiled program
 a request hits, nor any result bit (every search is self-contained).
 
-``AsyncDSEService`` runs the same service behind a worker thread:
+``AsyncDSEService`` runs the same launch cycle behind a worker thread:
 ``submit`` returns a ``concurrent.futures.Future`` immediately, requests
 submitted while a launch is in flight join the NEXT launch's packing
-(the dispatch/complete split below holds the lock only around queue
-surgery, never around ``engine.execute``), and an urgent submission
-therefore preempts all still-queued work at the next launch boundary.
+(the cycle holds the lock only around queue surgery, never around the
+engine's ``dispatch``/``harvest``), and an urgent submission therefore
+preempts all still-queued work at the next launch boundary.
 
 Because the ``table`` backend's traced ctx is layer-free, requests over
 *different* workload sets share one compiled program: 256 mixed requests
@@ -50,6 +52,7 @@ request (submit, dispatch and resolution stamps on ``perf_counter``).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
 import time
@@ -97,6 +100,26 @@ def _percentile(samples: Sequence[float], q: float) -> Optional[float]:
 # the percentiles describe recent traffic rather than all-time history.
 SAMPLE_WINDOW = 4096
 LAUNCH_LOG_WINDOW = 4096
+
+
+# a launch between dispatch and harvest: (plan, rids, dispatch stamp on
+# perf_counter, the engine's PendingLaunch)
+_InFlight = Tuple[BatchPlan, List[int], float, object]
+
+
+def _deliver(claimed: Sequence[Tuple[Optional[Future], object]],
+             exc: Optional[BaseException] = None) -> None:
+    """Resolve claimed futures OUTSIDE the front end's lock (their
+    done-callbacks may submit): each with its result, or with ``exc``
+    where the result is ``None``.  ``None`` futures were taken by a
+    timed-out ``close`` and are skipped."""
+    for fut, res in claimed:
+        if fut is None:
+            continue
+        if res is None:
+            fut.set_exception(exc)
+        else:
+            fut.set_result(res)
 
 
 @dataclasses.dataclass
@@ -258,17 +281,20 @@ class DSEService:
     the cache is shared with it; an explicitly passed engine keeps its
     own ``result_cache`` (and the service adopts it if not given one).
 
-    ``pipelined=True`` drains multi-plan queues double-buffered: each
-    ``stream``/``drain`` iteration DISPATCHES plan i+1 (JAX async — the
-    device starts computing) before HARVESTING plan i (the host-blocking
+    The engine must have ``SearchEngine``'s ``dispatch``/``harvest``
+    split.  Every front end drains through one launch cycle
+    (``_cycle``): sweep deadlines -> ``_dispatch`` -> ``engine.dispatch``
+    -> ``engine.harvest`` of the oldest launch in flight -> ``_complete``
+    or the failure path.  ``pipelined=True`` keeps one launch in flight
+    across cycles: each cycle DISPATCHES plan i+1 (JAX async — the device
+    starts computing) before HARVESTING plan i (the host-blocking
     finalize), so host packing of one launch overlaps device compute of
-    the next.  Results are bit-identical to the sequential drain — only
-    the launch interleaving changes — but results carry ``ga=None``
-    (transfer-thin; see ``SearchEngine``), so a shared result cache
-    stores cache-hits from sequential runs only.  The knob is inherited
-    from an explicitly passed engine's own ``pipelined`` flag when left
-    ``None``, and silently falls back to the sequential drain on engines
-    without the dispatch/harvest split (stubs, fault wrappers).
+    the next; otherwise a launch is harvested right after its own
+    dispatch.  Results are bit-identical either way — only the launch
+    interleaving changes — and a pipelined engine's results carry
+    ``ga=None`` (transfer-thin; see ``SearchEngine``).  The knob is
+    inherited from an explicitly passed engine's own ``pipelined`` flag
+    when left ``None``.
     """
 
     def __init__(
@@ -292,10 +318,6 @@ class DSEService:
             self.pipelined = bool(getattr(self.engine, "pipelined", False))
         else:
             self.pipelined = bool(pipelined)
-        # stub/wrapper engines (sim FakeEngine, fault injectors) have no
-        # dispatch/harvest split — they drain sequentially regardless
-        self._can_pipeline = (hasattr(self.engine, "dispatch")
-                              and hasattr(self.engine, "harvest"))
         self.result_cache = (
             result_cache if result_cache is not None
             else getattr(self.engine, "result_cache", None)
@@ -342,6 +364,12 @@ class DSEService:
         self._snapshot: List[Tuple[int, SearchRequest]] = []
         # mid-search best-so-far stream subscribers, per rid
         self._progress_cbs: Dict[int, Callable] = {}
+        # launches dispatched but not yet harvested, oldest first: at most
+        # one across cycles, under ``pipelined``
+        self._inflight: Deque[_InFlight] = deque()
+        # guards queue surgery and stats when this service is the front
+        # end (``_cycle``'s ``front``); a single caller needs no lock
+        self._lock = contextlib.nullcontext()
 
     # ------------------------------------------------------------- admission
     def submit(self, req: SearchRequest, *, on_progress=None) -> int:
@@ -395,7 +423,10 @@ class DSEService:
         return [self.submit(r) for r in reqs]
 
     def pending(self) -> int:
-        return len(self.queue) + len(self._retry_lane)
+        """Requests not yet resolved: queued, awaiting a retry, or in a
+        launch in flight."""
+        return (len(self.queue) + len(self._retry_lane)
+                + sum(len(f[1]) for f in self._inflight))
 
     # --------------------------------------------------------------- serving
     def _plans(self) -> List[BatchPlan]:
@@ -475,7 +506,7 @@ class DSEService:
             self.stats.wait_samples.pop()  # newest = this dispatch's
 
     def _rollback(self, plan: BatchPlan, rids: List[int]) -> None:
-        """Undo a dispatch whose launch failed (sync path): the requests
+        """Undo a dispatch whose launch failed (sync front end): the requests
         return to the queue with their original submit stamps intact —
         ``step()`` stays retryable — and the dispatch's wait samples are
         dropped (the requests were never served)."""
@@ -648,34 +679,113 @@ class DSEService:
 
         return {"on_progress": bridge}
 
-    def step(self) -> List[Tuple[int, SearchResult]]:
-        """Run ONE slot-packed launch (the policy's most urgent plan of
-        the current queue); returns that plan's (rid, result) pairs —
-        plus, under ``partial_results``, any deadline-swept partial
-        resolutions.  Requests submitted while a step runs simply join
-        the next plan.  With a ``retry`` policy an engine failure is
-        absorbed (retry lane / quarantine) instead of raised."""
+    # ------------------------------------------------------ launch cycle
+    def _admit(self) -> Tuple[List[Tuple[int, SearchResult]],
+                              Optional[Tuple[BatchPlan, List[int], float]]]:
+        """The admission half of a cycle, under the front end's lock:
+        deadline-swept partial resolutions, then the next dispatch (or
+        ``None``).  A front end's ``_admit`` returns ``None`` once it is
+        closed; the sync front end never closes."""
         swept = self._sweep_deadlines() if self.partial_results else []
-        d = self._dispatch()
-        if d is None:
-            return swept
-        plan, rids, td = d
-        try:
-            results = self.engine.execute(plan, **self._progress_kw(rids))
-        except Exception as e:
-            if self.retry is None:
-                self._rollback(plan, rids)  # step() stays retryable
-                raise
-            resolutions, _ = self._handle_failure(plan, rids, e)
-            return swept + resolutions
-        except BaseException:
-            # KeyboardInterrupt & co: always roll back and surface —
-            # the kill half of the kill/resume contract
-            self._rollback(plan, rids)
-            raise
+        return swept, self._dispatch()
+
+    def _claim(self, done: Sequence[Tuple[int, SearchResult]],
+               failed: Sequence[int] = ()
+               ) -> List[Tuple[Optional[Future], object]]:
+        """Under the front end's lock: the clients of ``done`` (rid,
+        result) pairs and ``failed`` rids, as (future, result or ``None``)
+        pairs for ``_deliver`` to resolve outside it.  The sync front
+        end's callers read results off ``step()``, so it has none."""
+        return []
+
+    def _cycle(self, front=None, admit: bool = True
+               ) -> Optional[List[Tuple[int, SearchResult]]]:
+        """One launch cycle — the only drain loop body, shared by
+        ``step()``/``stream()`` and the ``AsyncDSEService`` worker:
+        sweep deadlines -> ``_dispatch`` -> ``engine.dispatch`` ->
+        ``engine.harvest`` of the oldest launch in flight -> ``_complete``
+        or the failure path.  The depth in flight after a cycle that
+        dispatched is 1 under ``pipelined`` (it harvests the previous
+        launch, whose host finalize overlaps this one's device compute)
+        and 0 otherwise (a launch is harvested right after its own
+        dispatch).  A cycle that dispatches nothing harvests whatever is
+        in flight.
+
+        ``front`` is the front end (default: this service): its
+        ``_admit`` runs the admission half under its lock, and its
+        ``_lock`` guards ``_complete``, the failure path and the
+        ``_claim`` of the clients that ``_deliver`` resolves outside it.
+        The engine calls run outside every lock.  ``admit=False`` only
+        harvests (settling the launch in flight).  Returns the (rid,
+        result) pairs the cycle resolved, or ``None`` when the front end
+        is closed."""
+        front = self if front is None else front
+        out: List[Tuple[int, SearchResult]] = []
+        d = None
+        if admit:
+            got = front._admit()
+            if got is None:
+                return None
+            out, d = got
+        if d is not None:
+            plan, rids, td = d
+            try:
+                pend = self.engine.dispatch(plan, **self._progress_kw(rids))
+            except BaseException as e:  # noqa: BLE001 — the failure path decides
+                return out + self._settle(front, plan, rids, td, e)
+            self._inflight.append((plan, rids, td, pend))
+        if self._inflight and (d is None
+                               or len(self._inflight) > self.pipelined):
+            plan, rids, td, pend = self._inflight.popleft()
+            try:
+                results = self.engine.harvest(pend)
+            except BaseException as e:  # noqa: BLE001 — the failure path decides
+                results = e
+            out += self._settle(front, plan, rids, td, results)
+        return out
+
+    def _settle(self, front, plan: BatchPlan, rids: List[int], td: float,
+                results) -> List[Tuple[int, SearchResult]]:
+        """Resolve one launch under its ``dse.resolve`` span: complete its
+        harvested ``results``, or — ``results`` is then the exception —
+        take the failed launch down the failure path.  With a ``retry``
+        policy that is the retry lane / quarantine; without one the sync
+        front end rolls the launch back and re-raises (``step()`` stays
+        retryable), while a worker front end abandons its requests and
+        fails their futures.  A ``BaseException`` other than
+        ``Exception`` (KeyboardInterrupt & co) always rolls back and
+        surfaces on the sync front end — the kill half of the kill/resume
+        contract."""
+        failed: List[int] = []
         with spans.span("dse.resolve", launch=plan.launch, reqs=len(rids)):
-            return swept + self._complete(rids, results, plan.requests,
+            with front._lock:
+                if not isinstance(results, BaseException):
+                    done = self._complete(rids, results, plan.requests,
                                           plan.launch, td)
+                elif front is self and (self.retry is None or not
+                                        isinstance(results, Exception)):
+                    self._rollback(plan, rids)
+                    raise results
+                elif self.retry is None:
+                    self._abandon(rids)
+                    done, failed = [], rids
+                else:
+                    done, failed = self._handle_failure(plan, rids, results)
+                claimed = front._claim(done, failed)
+            _deliver(claimed, results)
+        return done
+
+    def step(self) -> List[Tuple[int, SearchResult]]:
+        """Run ONE launch cycle: dispatch the policy's most urgent plan of
+        the current queue and harvest the launch in flight — this one,
+        or under ``pipelined`` the previous one (a step with nothing left
+        to dispatch harvests it).  Returns the harvested launch's (rid,
+        result) pairs — plus, under ``partial_results``, any
+        deadline-swept partial resolutions.  Requests submitted while a
+        step runs simply join the next plan.  With a ``retry`` policy an
+        engine failure is absorbed (retry lane / quarantine) instead of
+        raised."""
+        return self._cycle()
 
     def _wait_for_retries(self) -> None:
         """Nothing dispatchable but retries are backed off: sleep the
@@ -686,93 +796,15 @@ class DSEService:
             if dt > 0:
                 self._sleep(dt)
 
-    def _harvest_one(
-        self, entry: Tuple[BatchPlan, List[int], float, object]
-    ) -> List[Tuple[int, SearchResult]]:
-        """Harvest one in-flight launch ``(plan, rids, td, pending)``:
-        blocks on the device sync and completes (or fails, mirroring
-        ``step()``'s fault handling) the launch's requests."""
-        plan, rids, td, pend = entry
-        try:
-            results = self.engine.harvest(pend)
-        except Exception as e:
-            if self.retry is None:
-                self._rollback(plan, rids)
-                raise
-            resolutions, _ = self._handle_failure(plan, rids, e)
-            return resolutions
-        except BaseException:
-            self._rollback(plan, rids)
-            raise
-        with spans.span("dse.resolve", launch=plan.launch, reqs=len(rids)):
-            return self._complete(rids, results, plan.requests, plan.launch,
-                                  td)
-
-    def _stream_pipelined(self) -> Iterator[Tuple[int, SearchResult]]:
-        """Double-buffered drain: dispatch plan i+1, THEN harvest plan i,
-        so the host-side finalize of one launch overlaps device compute
-        of the next.  At most one launch is in flight beyond the one
-        being harvested; any exception rolls the in-flight launch's
-        requests back into the queue before propagating."""
-        prev = None  # (plan, rids, td, pending) still in flight
-        try:
-            while True:
-                swept = (self._sweep_deadlines()
-                         if self.partial_results else [])
-                yield from swept
-                d = self._dispatch()
-                if d is None:
-                    if prev is not None:
-                        to_harvest, prev = prev, None
-                        yield from self._harvest_one(to_harvest)
-                        continue
-                    if not self.pending():
-                        return
-                    self._wait_for_retries()
-                    continue
-                plan, rids, td = d
-                try:
-                    pend = self.engine.dispatch(
-                        plan, **self._progress_kw(rids))
-                except Exception as e:
-                    # a failed dispatch resolves like a failed launch; the
-                    # in-flight prev is untouched and harvests next round
-                    if self.retry is None:
-                        self._rollback(plan, rids)
-                        raise
-                    resolutions, _ = self._handle_failure(plan, rids, e)
-                    yield from resolutions
-                    continue
-                except BaseException:
-                    self._rollback(plan, rids)
-                    raise
-                cur = (plan, rids, td, pend)
-                if prev is not None:
-                    # swap BEFORE harvesting: if the harvest raises, the
-                    # outer handler rolls back cur (prev already rolled
-                    # back inside _harvest_one), never double-rolls
-                    to_harvest, prev = prev, cur
-                    yield from self._harvest_one(to_harvest)
-                else:
-                    prev = cur
-        except BaseException:
-            if prev is not None:
-                self._rollback(prev[0], prev[1])
-            raise
-
     def stream(self) -> Iterator[Tuple[int, SearchResult]]:
         """Drain, yielding each plan's results as soon as its launch
         finishes — callers overlap their own post-processing with the
-        remaining launches.  Under ``pipelined=True`` (on an engine with
-        the dispatch/harvest split) the drain double-buffers launches;
-        same results, same per-plan yield boundaries."""
-        if self.pipelined and self._can_pipeline:
-            yield from self._stream_pipelined()
-            return
+        remaining launches."""
         while self.pending():
             out = self.step()
             yield from out
-            if not out and not self.queue and self.pending():
+            if (not out and not self.queue and not self._inflight
+                    and self.pending()):
                 self._wait_for_retries()
 
     def drain(self) -> Dict[int, SearchResult]:
@@ -789,8 +821,9 @@ class AsyncDSEService:
 
     ``submit`` enqueues and returns a ``concurrent.futures.Future``
     immediately — it never waits on a launch in flight, because the
-    worker holds the service lock only around ``_dispatch``/``_complete``
-    (queue surgery), never around ``engine.execute``.  A request
+    worker runs the service's launch cycle (``DSEService._cycle``) with
+    this front end's lock held only around queue surgery and stats,
+    never around the engine's ``dispatch``/``harvest``.  A request
     submitted mid-launch therefore joins the NEXT launch's packing, and
     under the priority/edf policies an urgent submission preempts every
     still-queued request at that boundary (the re-plan runs on warm
@@ -804,10 +837,10 @@ class AsyncDSEService:
     for reacting mid-drain (the integration test submits its priority-0
     jump there).  ``paused=True`` admits submissions without launching
     until ``resume()`` — batch admission with a deterministic first plan.
-    ``pipelined=True`` swaps the worker for a double-buffered loop
-    (dispatch plan i+1 before harvesting plan i — see ``DSEService``);
-    results and future-resolution order are unchanged.  Use as a context
-    manager, or call ``close()``."""
+    ``pipelined=True`` keeps one launch in flight (dispatch plan i+1
+    before harvesting plan i — see ``DSEService``); results and
+    future-resolution order are unchanged.  Use as a context manager, or
+    call ``close()``."""
 
     def __init__(
         self,
@@ -837,11 +870,11 @@ class AsyncDSEService:
             self._run.set()
         self._futures: Dict[int, Future] = {}
         self._closed = False
-        svc = self.service
-        target = (self._loop_pipelined
-                  if svc.pipelined and svc._can_pipeline else self._loop)
+        # seconds to the next backed-off retry, set by an admission that
+        # found nothing to dispatch and nothing in flight
+        self._retry_wait: Optional[float] = None
         self._worker = threading.Thread(
-            target=target, name="dse-service", daemon=True
+            target=self._loop, name="dse-service", daemon=True
         )
         self._worker.start()
 
@@ -892,167 +925,68 @@ class AsyncDSEService:
         self._wake.set()
 
     # --------------------------------------------------------------- serving
-    def _loop(self):
-        while True:
-            self._wake.wait()
-            self._run.wait()
-            svc = self.service
-            retry_wait = None
-            with self._lock:
-                if self._closed:
-                    return
-                swept = (svc._sweep_deadlines()
-                         if svc.partial_results else [])
-                partial_futs = [
-                    (self._futures.pop(rid, None), res) for rid, res in swept
-                ]
-                d = svc._dispatch()
-                if d is None:
-                    nb = svc._next_retry_due()
-                    if nb is None:
-                        self._wake.clear()
-                        if not self._futures:
-                            self._idle.set()
-                    else:
-                        retry_wait = max(nb - svc.clock(), 0.0)
-            # futures resolve OUTSIDE the lock: done-callbacks may submit
-            for f, res in partial_futs:
-                if f is not None:
-                    f.set_result(res)
-            if d is None:
-                if retry_wait is not None:
-                    # backed-off retries pending: nap on the REAL clock (a
-                    # virtual service clock advances externally), bounded
-                    # so external clock advances are picked up promptly
-                    time.sleep(min(retry_wait, 0.05) or 0.001)
-                continue
-            plan, rids, td = d
-            # the launch runs WITHOUT the lock: submits land concurrently
-            # and join the next dispatch's re-plan (progress callbacks
-            # fire here too — lock-free, so they may submit)
-            try:
-                results = svc.engine.execute(plan, **svc._progress_kw(rids))
-            except BaseException as e:  # noqa: BLE001 — fail the futures, keep serving
-                self._fail(plan, rids, e)
-                continue
-            self._resolve(plan, rids, td, results)
-
-    def _resolve(self, plan: BatchPlan, rids: List[int], td: float,
-                 results: Sequence[SearchResult]) -> None:
-        """Complete a harvested launch under the lock, then resolve its
-        futures OUTSIDE it (done-callbacks may submit); one ``dse.resolve``
-        span covers both."""
+    def _admit(self):
+        """The admission half of the service's launch cycle, under the
+        lock (``None`` once closed).  With nothing to dispatch and nothing
+        in flight the worker goes idle — the wake flag clears in the same
+        lock section that saw the queue empty, so no submit is missed —
+        or, with retries backed off, notes how long to nap.  Swept
+        partials resolve outside the lock."""
         svc = self.service
-        with spans.span("dse.resolve", launch=plan.launch, reqs=len(rids)):
-            with self._lock:
-                done = svc._complete(rids, results, plan.requests,
-                                     plan.launch, td)
-                futs = [(self._futures.pop(rid, None), r) for rid, r in done]
-            for f, r in futs:
-                if f is not None:
-                    f.set_result(r)
-
-    def _fail(self, plan: BatchPlan, rids: List[int],
-              e: BaseException) -> None:
-        """A failed launch (dispatch or harvest): abandon its requests, or
-        hand them to the retry policy, under the lock; then resolve or
-        fail their futures OUTSIDE it — done-callbacks fire on failure
-        too, and may submit.  One ``dse.resolve`` span covers both."""
-        svc = self.service
-        with spans.span("dse.resolve", launch=plan.launch, reqs=len(rids)):
-            with self._lock:
-                if svc.retry is None:
-                    svc._abandon(rids)
-                    resolved = []
-                    failed = [self._futures.pop(rid, None) for rid in rids]
+        self._retry_wait = None
+        with self._lock:
+            if self._closed:
+                return None
+            swept, d = svc._admit()
+            claimed = self._claim(swept)
+            if d is None and not svc._inflight:
+                nb = svc._next_retry_due()
+                if nb is None:
+                    self._wake.clear()
+                    if not self._futures:
+                        self._idle.set()
                 else:
-                    res2, bad = svc._handle_failure(plan, rids, e)
-                    resolved = [(self._futures.pop(rid, None), r)
-                                for rid, r in res2]
-                    failed = [self._futures.pop(rid, None) for rid in bad]
-            for f, r in resolved:
-                if f is not None:
-                    f.set_result(r)
-            for f in failed:
-                if f is not None:
-                    f.set_exception(e)
+                    self._retry_wait = max(nb - svc.clock(), 0.0)
+        _deliver(claimed)
+        return swept, d
 
-    def _loop_pipelined(self):
-        """The double-buffered worker: dispatch plan i+1 (lock-free — the
-        device starts computing), then harvest plan i (the blocking sync).
-        Queue surgery and stats stay under the lock exactly as in
-        ``_loop``; futures always resolve outside it.  ``pause()`` and
-        ``close()`` both finish the in-flight launch before stopping."""
+    def _claim(self, done: Sequence[Tuple[int, SearchResult]],
+               failed: Sequence[int] = ()
+               ) -> List[Tuple[Optional[Future], object]]:
+        """Pop the futures of ``done`` and ``failed`` under the lock, so
+        a timed-out ``close`` (which cancels what is left in the map)
+        and the worker never both own one; a future it already took
+        comes back as ``None``."""
+        return ([(self._futures.pop(rid, None), res) for rid, res in done]
+                + [(self._futures.pop(rid, None), None) for rid in failed])
+
+    def _loop(self):
+        """The worker: the service's launch cycles (one launch in flight
+        under ``pipelined``).  It blocks while idle or
+        paused; ``pause()`` and ``close()`` both settle the launch in
+        flight first.  Progress callbacks fire inside the engine calls,
+        lock-free, so they may submit."""
         svc = self.service
-
-        def harvest_entry(entry):
-            plan, rids, td, pend = entry
-            try:
-                results = svc.engine.harvest(pend)
-            except BaseException as e:  # noqa: BLE001 — fail the futures, keep serving
-                self._fail(plan, rids, e)
-                return
-            self._resolve(plan, rids, td, results)
-
-        prev = None  # (plan, rids, td, pending) still in flight
         while True:
-            if prev is None:
+            if not svc._inflight:
                 self._wake.wait()
                 self._run.wait()
             elif not self._run.is_set():
                 # paused mid-overlap: settle the in-flight launch, then
                 # block at the top of the next iteration
-                to_harvest, prev = prev, None
-                harvest_entry(to_harvest)
+                svc._cycle(self, admit=False)
                 continue
-            retry_wait = None
-            d = None
-            with self._lock:
-                if self._closed:
-                    break
-                swept = (svc._sweep_deadlines()
-                         if svc.partial_results else [])
-                partial_futs = [
-                    (self._futures.pop(rid, None), res) for rid, res in swept
-                ]
-                d = svc._dispatch()
-                if d is None:
-                    nb = svc._next_retry_due()
-                    if nb is None and prev is None:
-                        self._wake.clear()
-                        if not self._futures:
-                            self._idle.set()
-                    elif nb is not None:
-                        retry_wait = max(nb - svc.clock(), 0.0)
-            for f, res in partial_futs:
-                if f is not None:
-                    f.set_result(res)
-            if d is None:
-                if prev is not None:
-                    to_harvest, prev = prev, None
-                    harvest_entry(to_harvest)
-                elif retry_wait is not None:
-                    time.sleep(min(retry_wait, 0.05) or 0.001)
-                continue
-            # dispatch WITHOUT the lock: it only enqueues device work
-            # (progress callbacks fire here too, and may submit)
-            plan, rids, td = d
-            try:
-                pend = svc.engine.dispatch(plan, **svc._progress_kw(rids))
-            except BaseException as e:  # noqa: BLE001 — fail the futures, keep serving
-                self._fail(plan, rids, e)
-                continue
-            cur = (plan, rids, td, pend)
-            if prev is not None:
-                to_harvest, prev = prev, cur
-                harvest_entry(to_harvest)
-            else:
-                prev = cur
+            if svc._cycle(self) is None:
+                break
+            if self._retry_wait is not None:
+                # backed-off retries pending: nap on the REAL clock (a
+                # virtual service clock advances externally), bounded
+                # so external clock advances are picked up promptly
+                time.sleep(min(self._retry_wait, 0.05) or 0.001)
         # closed with a launch still in flight (timed-out close cancelled
-        # its futures): settle it so engine bookkeeping stays consistent —
-        # the pops above see an empty future map and skip
-        if prev is not None:
-            harvest_entry(prev)
+        # its futures): settle it so engine bookkeeping stays consistent
+        while svc._inflight:
+            svc._cycle(self, admit=False)
 
     def drain(self, timeout: Optional[float] = None) -> Dict[int, SearchResult]:
         """Block until the queue and all in-flight launches are done;

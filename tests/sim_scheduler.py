@@ -9,10 +9,11 @@ those claims assertable without a single device launch:
   * ``VirtualClock``  — the service's only time source; tests advance it
     explicitly, so waits, deadlines and latency stats are exact numbers,
     not wall-clock noise.
-  * ``StubEngine``    — duck-types ``SearchEngine.execute``: returns a
-    ``SimResult`` per real request (echoing seed/names, so every rid can
-    be checked against its own request), advances the clock by a
-    scripted per-launch duration, and records each launch.
+  * ``StubEngine``    — duck-types ``SearchEngine``'s ``dispatch`` /
+    ``harvest`` split: a launch runs whole at harvest (``execute``),
+    returns a ``SimResult`` per real request (echoing seed/names, so
+    every rid can be checked against its own request), advances the
+    clock by a scripted per-launch duration, and records each launch.
   * ``sim_service``   — a ``DSEService`` wired to both.
   * ``run_script``    — drives a scripted submit / advance / step
     interleaving and returns the completion record.
@@ -114,9 +115,12 @@ class SimLaunch:
 
 class StubEngine:
     """Duck-types the half of ``SearchEngine`` the service consumes:
-    ``max_slots`` and ``execute(plan)``.  Each execute advances the
-    virtual clock by ``launch_s`` (a constant, or a callable of the
-    plan — scripted heterogeneous launch times) and logs the launch."""
+    ``max_slots`` and ``dispatch(plan)`` / ``harvest(pending)``.  The
+    pending launch is the plan itself; ``harvest`` runs it whole through
+    ``execute``, which subclasses override to script faults.  Each
+    execute advances the virtual clock by ``launch_s`` (a constant, or a
+    callable of the plan — scripted heterogeneous launch times) and logs
+    the launch."""
 
     def __init__(
         self,
@@ -129,6 +133,12 @@ class StubEngine:
         self.max_slots = int(max_slots)
         self.launch_s = launch_s
         self.launches: List[SimLaunch] = []
+
+    def dispatch(self, plan: BatchPlan) -> BatchPlan:
+        return plan
+
+    def harvest(self, plan: BatchPlan) -> List[SimResult]:
+        return self.execute(plan)
 
     def execute(self, plan: BatchPlan, *, mesh=None,
                 dt: Optional[float] = None) -> List[SimResult]:
@@ -234,16 +244,19 @@ def sim_service(
     retry: Optional[RetryPolicy] = None,
     partial_results: bool = False,
     engine_cls=StubEngine,
+    pipelined: bool = False,
     **engine_kw,
 ) -> Tuple[DSEService, VirtualClock, StubEngine]:
     """A service on the virtual clock.  ``engine_cls=FaultyEngine`` (plus
     its kwargs) wires in fault injection; ``sleep`` is the clock's own
-    ``advance``, so drains wait out retry backoff in simulated time."""
+    ``advance``, so drains wait out retry backoff in simulated time.
+    ``pipelined`` keeps one launch in flight across steps."""
     clock = VirtualClock(t0)
     stub = engine_cls(clock, max_slots=max_slots, launch_s=launch_s,
                       **engine_kw)
     svc = DSEService(engine=stub, policy=policy, clock=clock, retry=retry,
-                     partial_results=partial_results, sleep=clock.advance)
+                     partial_results=partial_results, sleep=clock.advance,
+                     pipelined=pipelined)
     return svc, clock, stub
 
 

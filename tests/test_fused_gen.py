@@ -1,19 +1,14 @@
-"""Fused generation step, gen-step kernel, direct seeder, grid density.
+"""Fused generation step, direct seeder, grid density.
 
-The PR-8 fast path: ``core.ga`` fuses the survivor epilogue (one combined
-``lax.sort``) and optionally the WHOLE generation into a single Pallas
-kernel (``kernels.ga_gen_step``); the engine's ``direct_seed`` replaces
-the rejection seeding rounds with an inverse-CDF sampler over the
-feasible cells of the largest workload; ``space.configure_grid`` densifies
-the hardware grid.  Everything here pins BIT-parity between the fast and
-reference paths — the repo's invariant that a speedup must never change a
-result bit (unless, like ``direct_seed``, it is explicitly opt-in).
-
-NOTE on jit in the kernel-parity tests: both sides are compared as
-COMPILED programs.  Eager op-by-op execution differs from any single
-compiled program by 1 ULP on CPU (XLA contracts a*b+c into FMA when it
-compiles the whole expression), so eager-vs-kernel is NOT the invariant —
-jit-vs-kernel is, and ``run_ga`` always jits.
+``core.ga`` runs survival as one combined ``lax.sort`` per generation:
+the (mu + lambda) step (``_elitist_survival``) carries the scores through
+the sort of the folded score keys, and the NSGA-II step
+(``_crowded_survival``) carries the objective columns through the sort
+of the crowded-order keys.  Both are pinned here against their gather
+references under adversarial ties.  The engine's ``direct_seed``
+replaces the rejection seeding rounds with an inverse-CDF sampler over
+the feasible cells of the largest workload; ``space.configure_grid``
+densifies the hardware grid.
 """
 from __future__ import annotations
 
@@ -23,14 +18,8 @@ import numpy as np
 import pytest
 
 from repro.core import ga, space
-from repro.core.engine import (
-    INDEXED,
-    SearchEngine,
-    SearchRequest,
-    _ctx_eval,
-    plan_batch,
-)
-from repro.core.search import batched_search, run_search, separate_search
+from repro.core.engine import SearchEngine, SearchRequest, plan_batch
+from repro.core.search import run_search
 from repro.imc.tables import build_tables_arrays, evaluate_genomes_tables, table_bytes
 from repro.imc.tech import TECH
 from repro.workloads.cnn import PAPER_WORKLOADS, cnn_workload
@@ -50,136 +39,62 @@ def _same_result(a, b):
     assert float(a.ga.best_score) == float(b.ga.best_score)
 
 
-# --------------------------------------------------- fused-vs-unfused parity
-@pytest.mark.parametrize("backend", ["jnp", "table", "pallas"])
-def test_fused_unfused_parity_all_backends(ws, backend):
-    """The fused epilogue is a pure program-shape change: trajectories,
-    top designs and scores are bit-identical on every backend."""
-    key = jax.random.PRNGKey(11)
-    a = run_search(key, ws, pop_size=16, generations=4, backend=backend,
-                   fused=True)
-    b = run_search(key, ws, pop_size=16, generations=4, backend=backend,
-                   fused=False)
-    _same_result(a, b)
+# ------------------------------------------------------------ survival
+def _adversarial_scores(pattern: str, n: int, seed: int) -> np.ndarray:
+    """Scores whose order a float comparator gets wrong or leaves to
+    stability: repeated values with +inf infeasibles, mixed zero signs,
+    and large-magnitude negatives (sign-folded keys near the int32
+    extremes)."""
+    rng = np.random.default_rng(seed)
+    if pattern == "ties_inf":
+        s = rng.choice([0.5, 1.0, 2.0], n).astype(np.float32)
+        s[rng.random(n) < 0.4] = np.inf
+    elif pattern == "signed_zero":
+        s = rng.choice([-0.0, 0.0, -1.5, 1.5], n).astype(np.float32)
+    else:  # big_negative
+        s = (-rng.random(n) * 3.0e38).astype(np.float32)
+        s[::3] = s[0]
+        s[1::5] = -np.float32(np.finfo(np.float32).max)
+    return s
 
 
-@pytest.mark.parametrize("pop", [15, 17])
-def test_fused_unfused_parity_odd_pop(ws, pop):
-    key = jax.random.PRNGKey(5)
-    a = run_search(key, ws, pop_size=pop, generations=3, fused=True)
-    b = run_search(key, ws, pop_size=pop, generations=3, fused=False)
-    _same_result(a, b)
+@pytest.mark.parametrize("pattern", ["ties_inf", "signed_zero", "big_negative"])
+@pytest.mark.parametrize("pop", [8, 15, 16, 17])
+def test_elitist_survival_matches_reference(pop, pattern):
+    """The combined-sort survival keeps exactly the designs and score
+    bits of a gather by ``_survivor_indices`` (the stable argsort)."""
+    alls = _adversarial_scores(pattern, 2 * pop, seed=pop)
+    allg = np.random.default_rng(pop + 1).random(
+        (2 * pop, space.N_GENES)).astype(np.float32)
+    got_g, got_s = jax.jit(ga._elitist_survival)(
+        allg[:pop], alls[:pop], allg[pop:], alls[pop:])
+    order = np.asarray(ga._survivor_indices(jnp.asarray(alls), pop))
+    np.testing.assert_array_equal(order,
+                                  np.argsort(alls, kind="stable")[:pop])
+    np.testing.assert_array_equal(np.asarray(got_g), allg[order])
+    # bitwise: the carried scores keep their zero signs
+    np.testing.assert_array_equal(np.asarray(got_s).view(np.int32),
+                                  alls[order].view(np.int32))
 
 
-def test_fused_unfused_parity_ragged_batch(ws):
-    """Mixed workload subsets in one ragged batch: per-element parity."""
-    subsets = [[0], [1, 2], [0, 1, 2, 3]]
-    sets = [ws.subset(s) for s in subsets]
-    W = max(s.n for s in sets)
-    L = ws.feats.shape[1]
-    B = len(sets)
-    feats = np.zeros((B, W, L, 6), np.float32)
-    mask = np.zeros((B, W, L), bool)
-    for i, s in enumerate(sets):
-        feats[i, : s.n] = np.asarray(s.feats)
-        mask[i, : s.n] = np.asarray(s.mask)
-    keys = jnp.stack([jax.random.PRNGKey(100 + i) for i in range(B)])
-    ra = batched_search(keys, feats, mask, pop_size=12, generations=3,
-                        backend="table", fused=True)
-    rb = batched_search(keys, feats, mask, pop_size=12, generations=3,
-                        backend="table", fused=False)
-    for a, b in zip(ra, rb):
-        _same_result(a, b)
-
-
-def test_fused_unfused_parity_segmented(ws):
-    """Fused x segmented: the chained fused segments equal the single
-    unfused launch bit-for-bit (and vice versa)."""
-    key = jax.random.PRNGKey(23)
-    kw = dict(pop_size=14, generations=6, backend="table")
-    single = run_search(key, ws, fused=False, **kw)
-    seg_fused = run_search(
-        key, ws, engine=SearchEngine(segment_gens=2, fused=True), **kw)
-    _same_result(single, seg_fused)
-
-
-def test_separate_search_fused_parity(ws):
-    key = jax.random.PRNGKey(3)
-    ra = separate_search(key, ws, pop_size=12, generations=3,
-                         backend="table", fused=True)
-    rb = separate_search(key, ws, pop_size=12, generations=3,
-                         backend="table", fused=False)
-    for n in ws.names:
-        _same_result(ra[n], rb[n])
-
-
-# ------------------------------------------------------- gen-step kernel
-def _table_eval_ctx(ws, P):
-    tables = build_tables_arrays(ws.feats, ws.mask)
-    eval_fn = _ctx_eval(INDEXED, 0.0, TECH, "table")
-    ctx = (tables, jnp.int32(0), jnp.float32(1e9))
-    return eval_fn, ctx
-
-
-@pytest.mark.parametrize("pop", [8, 15, 16])
-def test_kernel_gen_step_matches_lax(ws, pop):
-    """One full fused-kernel generation == the lax gen step, compiled,
-    for every output (survivors, scores, children, child scores)."""
-    from repro.kernels.ga_gen_step import make_kernel_gen_step
-
-    eval_fn, ctx = _table_eval_ctx(ws, pop)
-    assert getattr(eval_fn, "gen_kernel_tech", None) is not None
-    gen_lax = ga._make_gen_step(eval_fn, ctx, pop, space.N_GENES,
-                                ga.SBX_PROB, ga.SBX_ETA, ga.MUT_ETA,
-                                fused=True)
-    kgen = make_kernel_gen_step(
-        eval_fn, ctx, pop_size=pop, n_genes=space.N_GENES,
-        sbx_prob=ga.SBX_PROB, sbx_eta=ga.SBX_ETA, mut_eta=ga.MUT_ETA)
-    assert kgen is not None
-
-    popg = space.random_genomes(jax.random.PRNGKey(7), pop)
-    scores = eval_fn(popg, ctx)
-    k = jax.random.fold_in(jax.random.PRNGKey(3), 0)
-    (p1, s1), (c1, cs1) = jax.jit(gen_lax)((popg, scores), k)
-    (p2, s2), (c2, cs2) = jax.jit(kgen)((popg, scores), k)
-    assert np.array_equal(np.asarray(p1), np.asarray(p2))
-    assert np.array_equal(np.asarray(s1), np.asarray(s2))
-    assert np.array_equal(np.asarray(c1), np.asarray(c2))
-    assert np.array_equal(np.asarray(cs1), np.asarray(cs2))
-
-
-def test_kernel_gen_step_chained_generations(ws):
-    """Several chained kernel generations track the lax trajectory."""
-    from repro.kernels.ga_gen_step import make_kernel_gen_step
-
+@pytest.mark.parametrize("m", [2, 3])
+def test_crowded_survival_matches_gather_reference(m):
+    """The objective columns carried through the NSGA-II survival sort
+    equal a gather by its index, with rank ties (duplicate vectors, tied
+    all-+inf infeasibles) and crowding ties (equal boundary distances)."""
     P = 12
-    eval_fn, ctx = _table_eval_ctx(ws, P)
-    gen_lax = jax.jit(ga._make_gen_step(
-        eval_fn, ctx, P, space.N_GENES, ga.SBX_PROB, ga.SBX_ETA,
-        ga.MUT_ETA, fused=True))
-    kgen = jax.jit(make_kernel_gen_step(
-        eval_fn, ctx, pop_size=P, n_genes=space.N_GENES,
-        sbx_prob=ga.SBX_PROB, sbx_eta=ga.SBX_ETA, mut_eta=ga.MUT_ETA))
-    popg = space.random_genomes(jax.random.PRNGKey(1), P)
-    ca = (popg, eval_fn(popg, ctx))
-    cb = ca
-    for g in range(4):
-        k = jax.random.fold_in(jax.random.PRNGKey(9), g)
-        ca, _ = gen_lax(ca, k)
-        cb, _ = kgen(cb, k)
-    assert np.array_equal(np.asarray(ca[0]), np.asarray(cb[0]))
-    assert np.array_equal(np.asarray(ca[1]), np.asarray(cb[1]))
-
-
-def test_kernel_hook_requires_table_eval():
-    """The kernel factory declines eval callbacks without a table ctx —
-    dense/jnp backends keep the lax gen step."""
-    from repro.kernels.ga_gen_step import make_kernel_gen_step
-
-    plain = lambda g, ctx: jnp.zeros(g.shape[0])  # noqa: E731
-    assert make_kernel_gen_step(plain, (None,), pop_size=8,
-                                n_genes=space.N_GENES, sbx_prob=0.9,
-                                sbx_eta=3.0, mut_eta=3.0) is None
+    rng = np.random.default_rng(m)
+    allo = rng.integers(0, 3, (2 * P, m)).astype(np.float32)
+    allo[rng.random(2 * P) < 0.25] = np.inf
+    allg = rng.random((2 * P, space.N_GENES)).astype(np.float32)
+    got_g, got_o = jax.jit(ga._crowded_survival)(
+        allg[:P], allo[:P], allg[P:], allo[P:])
+    rank, ckey = map(np.asarray,
+                     jax.jit(ga._crowded_order_keys)(jnp.asarray(allo)))
+    assert len(np.unique(rank)) < 2 * P and len(np.unique(ckey)) < 2 * P
+    idx = np.lexsort((np.arange(2 * P), ckey, rank))[:P]
+    np.testing.assert_array_equal(np.asarray(got_g), allg[idx])
+    np.testing.assert_array_equal(np.asarray(got_o), allo[idx])
 
 
 # -------------------------------------------------------- direct seeder
@@ -261,19 +176,6 @@ def test_configure_grid_densify_and_restore(ws):
         space.configure_grid(1)
     assert space.grid_token() == base_token
     assert {f: len(space.SPACE[f]) for f in space.FIELDS} == base_sizes
-
-
-def test_dense_grid_fused_unfused_parity(ws):
-    try:
-        space.configure_grid(2)
-        key = jax.random.PRNGKey(77)
-        a = run_search(key, ws, pop_size=12, generations=2,
-                       backend="table", fused=True)
-        b = run_search(key, ws, pop_size=12, generations=2,
-                       backend="table", fused=False)
-        _same_result(a, b)
-    finally:
-        space.configure_grid(1)
 
 
 # ------------------------------------------------------ batched finalize

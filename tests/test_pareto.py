@@ -8,9 +8,9 @@ The contract this module pins (ISSUE 10 tentpole):
     to a brute-force numpy O(N^2) dominance oracle, under adversarial
     inputs: duplicate decoded cells, -0.0/+0.0 ties, tied all-+inf
     infeasible rows, and NaN-guarded rows.
-  * **Mode invariance** — fused and unfused survival, thin and
-    history-returning runs, sequential and pipelined engines, table and
-    jnp backends all select the same front, bit-for-bit.
+  * **Mode invariance** — thin and history-returning runs, sequential,
+    pipelined and one-slot engines, table and jnp backends all select
+    the same front, bit-for-bit.
   * **Engine semantics** — ``SearchRequest(objective="pareto")`` plans
     into its own signature group, validates eagerly, returns per-member
     (E, L, A) ``objective_vectors``, and round-trips the result cache.
@@ -215,13 +215,13 @@ def _toy_eval(genomes, _ctx=None):
     return jnp.where(feas[:, None], objs, jnp.inf)
 
 
-def _toy_run(fused, history, top_k=K, B=3):
+def _toy_run(history, top_k=K, B=3):
     keys = jax.random.split(jax.random.PRNGKey(0), B)
     init = jax.vmap(lambda k: space.random_genomes(k, POP))(
         jax.random.split(jax.random.PRNGKey(1), B))
     return run_pareto_batched(
         keys, _toy_eval, pop_size=POP, generations=GENS,
-        init_genomes=init, top_k=top_k, fused=fused, history=history)
+        init_genomes=init, top_k=top_k, history=history)
 
 
 def _assert_thin_equal(a: ParetoThin, b: ParetoThin):
@@ -232,7 +232,7 @@ def _assert_thin_equal(a: ParetoThin, b: ParetoThin):
 def test_front_matches_numpy_oracle_over_evaluated_designs():
     """Acceptance: the returned k-member front is bit-identical to the
     numpy dominance oracle replayed over the SAME evaluated designs."""
-    gh, oh, thin = _toy_run(fused=True, history=True)
+    gh, oh, thin = _toy_run(history=True)
     for b in range(np.asarray(gh).shape[0]):
         oracle = np_pareto_epilogue(np.asarray(gh)[b], np.asarray(oh)[b], K)
         got = ParetoThin(*(np.asarray(f)[b] for f in thin))
@@ -257,14 +257,11 @@ def test_front_matches_numpy_oracle_over_evaluated_designs():
 
 
 def test_fused_unfused_and_thin_history_parity():
-    """Fused vs unfused NSGA-II survival and thin vs history-returning
-    runs are all bit-identical; the standalone batched epilogue over the
-    returned history reproduces the fused-in thin outputs."""
-    thin_f = _toy_run(fused=True, history=False)
-    thin_u = _toy_run(fused=False, history=False)
-    gh, oh, thin_h = _toy_run(fused=True, history=True)
-    _assert_thin_equal(ParetoThin(*map(np.asarray, thin_f)),
-                       ParetoThin(*map(np.asarray, thin_u)))
+    """Thin vs history-returning runs are bit-identical; the standalone
+    batched epilogue over the returned history reproduces the fused-in
+    thin outputs."""
+    thin_f = _toy_run(history=False)
+    gh, oh, thin_h = _toy_run(history=True)
     _assert_thin_equal(ParetoThin(*map(np.asarray, thin_f)),
                        ParetoThin(*map(np.asarray, thin_h)))
     standalone = pareto_epilogue_batched(np.asarray(gh), np.asarray(oh),
@@ -276,8 +273,7 @@ def test_fused_unfused_and_thin_history_parity():
 def test_large_k_covers_whole_first_front_before_spilling():
     """With top_k >= #evaluated designs the picks enumerate every unique
     feasible cell in crowded order: rank-0 cells first, then rank 1..."""
-    gh, oh, thin = _toy_run(fused=True, history=True, top_k=(GENS + 1) * POP,
-                            B=1)
+    gh, oh, thin = _toy_run(history=True, top_k=(GENS + 1) * POP, B=1)
     oh0 = np.asarray(oh)[0].reshape(-1, N_PARETO)
     rank = np_dominance_rank(oh0)
     kept = int(np.asarray(thin.n_kept)[0])
@@ -310,14 +306,14 @@ def _pareto_reqs(ws, backend, n=3):
 
 @pytest.mark.parametrize("backend", ["table", "jnp"])
 def test_engine_modes_bit_identical(ws, backend):
-    """Sequential vs pipelined vs unfused engines return the same front
-    bits on both backends; pipelined results are thin (ga=None) but carry
-    identical vectors/designs."""
+    """Sequential vs pipelined vs one-slot engines return the same front
+    bits on both backends: slot packing moves no bit, and pipelined
+    results are thin (ga=None) but carry identical vectors/designs."""
     reqs = _pareto_reqs(ws, backend)
     seq = SearchEngine().run(reqs)
     pipe = SearchEngine(pipelined=True).run(reqs)
-    unfused = SearchEngine(fused=False).run(reqs)
-    for a, b, c in zip(seq, pipe, unfused):
+    alone = SearchEngine(max_slots=1).run(reqs)
+    for a, b, c in zip(seq, pipe, alone):
         assert a.objective == PARETO
         assert a.ga is not None and b.ga is None and c.ga is not None
         for other in (b, c):

@@ -142,10 +142,11 @@ def test_pipelined_parity_segmented_chain(ws):
 
 
 def test_pipelined_fused_cross_parity(ws):
-    """pipelined x fused compose: both knobs on equals both knobs off."""
+    """pipelined x direct_seed compose: the thin engine over direct seed
+    pools equals the sequential engine over the same pools."""
     reqs = _reqs(ws, 2, seed0=30)
-    ref = SearchEngine(fused=False).run(reqs)
-    out = SearchEngine(fused=True, pipelined=True).run(reqs)
+    ref = SearchEngine(direct_seed=True).run(reqs)
+    out = SearchEngine(direct_seed=True, pipelined=True).run(reqs)
     for a, b in zip(out, ref):
         _same_thin(a, b)
 
@@ -455,17 +456,29 @@ def test_async_service_pipelined_parity(ws):
 
 
 def test_service_pipelined_falls_back_on_stub_engines(ws):
-    """Engines without the dispatch/harvest split (sim stubs, fault
-    wrappers) drain sequentially even under pipelined=True."""
+    """A wrapper engine with just the dispatch/harvest split (no
+    ``pipelined`` flag, full-history results) drains pipelined: one
+    launch stays in flight while the next dispatches."""
     class MiniEngine:
-        max_slots = 4
+        max_slots = 1
         result_cache = None
 
-        def execute(self, plan, **kw):
-            return SearchEngine().execute(plan)
+        def __init__(self):
+            self.inner = SearchEngine(max_slots=1)
+            self.in_flight = 0
+            self.peak = 0
 
-    svc = DSEService(engine=MiniEngine(), pipelined=True)
-    assert not svc._can_pipeline
+        def dispatch(self, plan, **kw):
+            self.in_flight += 1
+            self.peak = max(self.peak, self.in_flight)
+            return self.inner.dispatch(plan, **kw)
+
+        def harvest(self, pending):
+            self.in_flight -= 1
+            return self.inner.harvest(pending)
+
+    eng = MiniEngine()
+    svc = DSEService(engine=eng, pipelined=True)
     # seeds whose tiny-budget searches find a feasible design (seed 130
     # finds none under JAX 0.9's random streams)
     reqs = _reqs(ws, 2, seed0=132)
@@ -476,3 +489,4 @@ def test_service_pipelined_falls_back_on_stub_engines(ws):
         assert out[rid].valid
         np.testing.assert_array_equal(out[rid].top_scores, want.top_scores)
         np.testing.assert_array_equal(out[rid].top_genomes, want.top_genomes)
+    assert eng.peak == 2 and eng.in_flight == 0

@@ -318,22 +318,21 @@ def test_separate_search_mesh_requires_batched(ws):
                         mesh=make_search_mesh(1, 1), pop_size=8, generations=1)
 
 
-# ------------------------------------------------------- fused fast path
+# ------------------------------------------------------- table fast path
 @pytest.mark.multidevice
 @pytest.mark.parametrize("searches,pop", MESH_LAYOUTS)
 def test_batched_search_sharded_fused_parity(ws, searches, pop):
-    """Fused x sharded, crossed: the sharded FUSED table run equals the
-    unsharded UNFUSED reference bit-for-bit — neither the mesh layout nor
-    the fused program shape may move a result bit."""
+    """Table backend x sharded with per-element workload sets that differ
+    (reversed order flips which rows are padding) under every mesh
+    layout: bit-identical to the unsharded table run."""
     mesh = make_search_mesh(searches, pop)
-    B = 8
-    keys = jnp.stack([jax.random.PRNGKey(900 + i) for i in range(B)])
-    feats = jnp.broadcast_to(ws.feats[None], (B,) + ws.feats.shape)
-    mask = jnp.broadcast_to(ws.mask[None], (B,) + ws.mask.shape)
+    feats = jnp.stack([ws.feats, ws.feats[::-1]] * 4)  # (8, W, L, 6)
+    mask = jnp.stack([ws.mask, ws.mask[::-1]] * 4)
+    keys = jnp.stack([jax.random.PRNGKey(900 + i) for i in range(8)])
     ref = batched_search(keys, feats, mask, pop_size=POP, generations=GENS,
-                         backend="table", fused=False)
+                         backend="table")
     sh = batched_search(keys, feats, mask, pop_size=POP, generations=GENS,
-                        backend="table", fused=True, mesh=mesh)
+                        backend="table", mesh=mesh)
     for r, s in zip(ref, sh):
         _assert_results_equal(r, s)
 
@@ -351,27 +350,25 @@ def test_sharded_direct_seed_parity(ws):
     mask = jnp.broadcast_to(ws.mask[None], (B,) + ws.mask.shape)
     kw = dict(pop_size=POP, generations=GENS, backend="table")
     ref = batched_search(keys, feats, mask,
-                         engine=SearchEngine(direct_seed=True, fused=True),
-                         **kw)
+                         engine=SearchEngine(direct_seed=True), **kw)
     sh = batched_search(keys, feats, mask,
-                        engine=SearchEngine(direct_seed=True, fused=True,
-                                            mesh=mesh),
+                        engine=SearchEngine(direct_seed=True, mesh=mesh),
                         **kw)
     for r, s in zip(ref, sh):
         _assert_results_equal(r, s)
 
 
 def test_fused_trivial_mesh_parity(ws):
-    """Single-device envelope of the fused x mesh cross (tier-1)."""
+    """Single-device envelope of the table x mesh cross (tier-1)."""
     mesh = make_search_mesh(1, 1)
     B = 2
     keys = jnp.stack([jax.random.PRNGKey(i) for i in range(B)])
     feats = jnp.broadcast_to(ws.feats[None], (B,) + ws.feats.shape)
     mask = jnp.broadcast_to(ws.mask[None], (B,) + ws.mask.shape)
     ref = batched_search(keys, feats, mask, pop_size=8, generations=2,
-                         backend="table", fused=False)
+                         backend="table")
     sh = batched_search(keys, feats, mask, pop_size=8, generations=2,
-                        backend="table", fused=True, mesh=mesh)
+                        backend="table", mesh=mesh)
     for r, s in zip(ref, sh):
         _assert_results_equal(r, s)
 

@@ -382,16 +382,16 @@ def test_sync_service_failed_launch_leaves_no_request_record(ws):
     stamps for the retry, and leave no record until served."""
     reqs = _reqs(ws, 2, seed0=70)
     svc = DSEService(max_slots=4)
-    real = svc.engine.execute
+    real = svc.engine.harvest
     calls = []
 
-    def flaky(plan, **kw):
-        calls.append(plan)
+    def flaky(pending):
+        calls.append(pending.plan)
         if len(calls) == 1:
             raise RuntimeError("injected")
-        return real(plan, **kw)
+        return real(pending)
 
-    svc.engine.execute = flaky
+    svc.engine.harvest = flaky
     t0 = time.perf_counter()
     rids = svc.submit_all(reqs)
     with pytest.raises(RuntimeError, match="injected"):

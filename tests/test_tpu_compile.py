@@ -38,7 +38,6 @@ from repro.core.ga import (
 )
 from repro.core.objectives import PARETO
 from repro.imc.tech import TECH
-from repro.kernels.ga_gen_step.kernel import ga_gen_step_pallas
 from repro.kernels.imc_eval.kernel import imc_eval_pallas_multi
 from repro.workloads.cnn import PAPER_WORKLOADS, cnn_workload
 from repro.workloads.pack import pack_workloads
@@ -117,7 +116,7 @@ def test_imc_eval_kernel_compiles(one_chip, ws, pop):
 
 
 def test_thin_table_ga_program_compiles(one_chip, ws):
-    """The service's main program: the fused, thin, table-backend batched
+    """The service's main program: the thin, table-backend batched
     GA with the indexed objective, one full 64-slot launch."""
     sh = lambda shape: one_chip  # noqa: E731
     compiled = _run_ga_batched_thin_jit.lower(
@@ -127,7 +126,7 @@ def test_thin_table_ga_program_compiles(one_chip, ws):
          _sds((SLOTS,), jnp.float32, one_chip)),
         eval_fn=_ctx_eval(INDEXED, 0.0, TECH, "table"), pop_size=POP,
         generations=GENS, sbx_prob=SBX_PROB, sbx_eta=SBX_ETA,
-        mut_eta=MUT_ETA, fused=True, top_k=TOP_K,
+        mut_eta=MUT_ETA, top_k=TOP_K,
     ).compile()
     _fits_hbm(compiled)
 
@@ -158,7 +157,7 @@ def test_pareto_program_compiles(one_chip, ws):
         (_table_ctx(ws, 1, sh), _sds((1,), jnp.float32, one_chip)),
         eval_fn=_ctx_eval(PARETO, 0.0, TECH, "table"), pop_size=pop,
         generations=gens, sbx_prob=SBX_PROB, sbx_eta=SBX_ETA,
-        mut_eta=MUT_ETA, fused=True, top_k=TOP_K, history=False,
+        mut_eta=MUT_ETA, top_k=TOP_K, history=False,
     ).compile()
     _fits_hbm(compiled)
 
@@ -182,34 +181,7 @@ def test_sharded_thin_program_compiles_for_four_chips(topo, ws):
          _sds((SLOTS,), jnp.float32, place((SLOTS,)))),
         eval_fn=_ctx_eval(INDEXED, 0.0, TECH, "table"), pop_size=POP,
         generations=GENS, sbx_prob=SBX_PROB, sbx_eta=SBX_ETA,
-        mut_eta=MUT_ETA, fused=True, top_k=TOP_K,
+        mut_eta=MUT_ETA, top_k=TOP_K,
     ).compile()
     _fits_hbm(compiled)
     assert len(compiled.output_shardings[0].device_set) == 4
-
-
-@pytest.mark.xfail(
-    strict=True, raises=NotImplementedError,
-    reason="Mosaic refuses the whole-generation kernel: 'Unimplemented "
-           "primitive in Pallas TPU lowering for KernelType.TC: rev' (the "
-           "jnp.flip partner exchange of the bitonic survival sort); past "
-           "it, 'infer-vector-layout: unsupported shape cast' on the rank-3 "
-           "one-hot selects; ROADMAP 1.5/3.4",
-)
-@pytest.mark.parametrize("pop", [64, POP])
-def test_ga_gen_step_kernel_compiles(one_chip, ws, pop):
-    n = space.N_GENES
-    n_pairs = (pop + 1) // 2
-    tot = 4 * n_pairs + 2 * n_pairs * n + n_pairs + 2 * pop * n
-    fn = jax.jit(lambda *a: ga_gen_step_pallas(
-        *a, sbx_prob=SBX_PROB, sbx_eta=SBX_ETA, mut_eta=MUT_ETA,
-        interpret=False))
-    fn.lower(
-        _sds((pop, n), jnp.float32, one_chip),
-        _sds((pop,), jnp.float32, one_chip),
-        _sds((tot,), jnp.float32, one_chip),
-        jax.tree_util.tree_map(
-            lambda a: _sds(a.shape, a.dtype, one_chip), ws.tables()),
-        _sds((), jnp.int32, one_chip),
-        _sds((), jnp.float32, one_chip),
-    ).compile()

@@ -6,23 +6,7 @@
 #   bash tools/ci.sh bench-smoke   # tiny search-throughput run per backend;
 #                                  # appends the 'table', 'service',
 #                                  # 'fused' and 'pipelined' rows of
-#                                  # experiments/search_throughput.json so
-#                                  # the perf trajectory is recorded per
-#                                  # PR, then FAILS if the fused fast
-#                                  # path's warm designs/s fell below the
-#                                  # unfused table row (the fusion must
-#                                  # never regress into a slowdown), if
-#                                  # the pipelined row fell below fused,
-#                                  # or if its host-transfer reduction
-#                                  # dropped under 10x
-#   bash tools/ci.sh perf-smoke    # fused-path gate: the fused/kernel/
-#                                  # seeder/grid parity suite plus the
-#                                  # pipelined-engine parity suite
-#                                  # (tests/test_pipelined.py), a quick
-#                                  # fused bench row and a pipelined row,
-#                                  # and the fused>=table /
-#                                  # pipelined>=fused / 10x-transfer
-#                                  # regression gates
+#                                  # experiments/search_throughput.json
 #   bash tools/ci.sh serve-smoke   # DSE-service smoke, three legs: sync
 #                                  # fifo (~32 mixed requests, all results
 #                                  # finite), sync EDF (launch order ==
@@ -47,8 +31,7 @@
 #                                  # + async + PIPELINED thin-result
 #                                  # engine) — zero new GA launches,
 #                                  # bit-identical results; records the
-#                                  # 'cache' row and gates its pipelined-
-#                                  # resubmit record (launches == 0)
+#                                  # 'cache' row
 #   bash tools/ci.sh pareto-smoke  # Pareto-front gate: the NSGA-II
 #                                  # numpy-oracle parity suite
 #                                  # (tests/test_pareto.py) and a quick
@@ -76,12 +59,6 @@ elif [[ "${1:-}" == "bench-smoke" ]]; then
   python -m benchmarks.bench_search_throughput --quick --pipelined
   python -m benchmarks.bench_search_throughput --quick --pareto
   python -m benchmarks.bench_dse_service --quick
-  python -m tools.check_fused_gate
-elif [[ "${1:-}" == "perf-smoke" ]]; then
-  python -m pytest -x -q tests/test_fused_gen.py tests/test_pipelined.py
-  python -m benchmarks.bench_search_throughput --quick --fused --grid-density 1,2
-  python -m benchmarks.bench_search_throughput --quick --pipelined
-  python -m tools.check_fused_gate
 elif [[ "${1:-}" == "serve-smoke" ]]; then
   python -m pytest -x -q tests/test_scheduler_sim.py
   python -m benchmarks.bench_dse_service --smoke
@@ -92,7 +69,6 @@ elif [[ "${1:-}" == "cache-smoke" ]]; then
   python -m pytest -x -q tests/test_result_cache.py
   python -m benchmarks.bench_dse_service --cache-smoke
   python -m benchmarks.bench_dse_service --cache --quick
-  python -m tools.check_fused_gate --cache
 elif [[ "${1:-}" == "pareto-smoke" ]]; then
   python -m pytest -x -q tests/test_pareto.py
   python -m benchmarks.bench_search_throughput --quick --pareto
